@@ -13,7 +13,8 @@ Per kernel it reports:
   map constant over the grid — e.g. the scalar-prefetched weight slabs
   in ``snn_chunk``), two copies of every *pipelined* block (Pallas
   double-buffers blocks whose index map varies), plus scratch;
-- estimated SMEM bytes (the scalar-prefetch operands);
+- estimated SMEM bytes (the scalar-prefetch operands, plus the copies
+  of every block whose BlockSpec asks for SMEM);
 - an index-map bounds check: every index map is evaluated at every grid
   corner and the produced block must lie inside the (padded) operand;
 - a divisibility check: padded operand dims must be multiples of the
@@ -23,7 +24,7 @@ Findings use codes RB301 (VMEM over budget), RB302 (index map out of
 bounds), RB303 (block does not divide operand), RB304 (SMEM over
 budget).  Budgets are configurable; defaults are the v4/v5 TPU figures
 from the Pallas guide (16 MiB VMEM/core) with a deliberately tight
-1 MiB line for scalar-prefetch SMEM.  The estimate covers *declared*
+1 MiB line for SMEM.  The estimate covers *declared*
 buffers only — compiler-managed temporaries (e.g. the (bm, bk, bn)
 int32 product in ``q115_matmul``) are the compiler's to spill.
 """
@@ -41,7 +42,7 @@ from jax.experimental import pallas as pl
 from .jaxlint import Finding
 
 DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024  # per-core VMEM (TPU v4/v5 class)
-DEFAULT_SMEM_BUDGET = 1024 * 1024  # scalar-prefetch tables
+DEFAULT_SMEM_BUDGET = 1024 * 1024  # scalar-prefetch tables + SMEM blocks
 
 # the (4096, 512, 2) collision config at serving geometry — the paper's
 # headline workload and what stream_bench drives
@@ -197,6 +198,7 @@ def _analyze_record(name: str, rec: dict) -> KernelPlan:
     buffers: list[BufferPlan] = []
     errors: list[str] = []
     smem = 0
+    smem_blocks: list[int] = []
 
     # scalar-prefetch operands live whole in SMEM
     for i in range(npf):
@@ -205,7 +207,10 @@ def _analyze_record(name: str, rec: dict) -> KernelPlan:
 
     def add(spec, operand_shape, dtype, role, label):
         nonlocal errors
-        bshape = tuple(int(b) for b in (spec.block_shape or ()))
+        # a squeezed dim (None) is a block of one element
+        bshape = tuple(
+            1 if b is None else int(b) for b in (spec.block_shape or ())
+        )
         if not bshape:
             bshape = tuple(int(s) for s in operand_shape)
         per_copy = int(np.prod(bshape)) * _itemsize(dtype)
@@ -233,6 +238,10 @@ def _analyze_record(name: str, rec: dict) -> KernelPlan:
                         f"{label}: block dim {d} ({bs}) does not divide "
                         f"operand dim ({os})"
                     )
+        if "smem" in str(getattr(spec, "memory_space", "")).lower():
+            # a pipelined SMEM block: its copies count against SMEM
+            smem_blocks.append(per_copy * (1 if resident else 2))
+            return
         buffers.append(
             BufferPlan(
                 name=label,
@@ -267,7 +276,7 @@ def _analyze_record(name: str, rec: dict) -> KernelPlan:
                            np.dtype(jnp.dtype(dtype)).name, nbytes, 1, True)
             )
 
-    return KernelPlan(name, grid, npf, buffers, smem, errors)
+    return KernelPlan(name, grid, npf, buffers, smem + sum(smem_blocks), errors)
 
 
 # ---------------------------------------------------------------------------
@@ -394,7 +403,7 @@ def check_kernel_budgets(
             findings.append(
                 Finding(
                     path, 1, 0, "RB304",
-                    f"{name}: scalar-prefetch SMEM {plan.smem_bytes / 2**10:.0f} KiB "
+                    f"{name}: SMEM {plan.smem_bytes / 2**10:.0f} KiB "
                     f"exceeds budget {smem_budget / 2**10:.0f} KiB",
                 )
             )

@@ -145,26 +145,6 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-# --------------------------------------------------------------- shard_map
-# jax moved shard_map out of experimental and renamed check_rep->check_vma;
-# wrap both spellings so sharded code runs on every container toolchain
-# (shared by distributed/pipeline.py and serving/snn_engine.py).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = {"check_vma": False}
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = {"check_rep": False}
-
-
-def shard_map_unchecked(fn, mesh: Mesh, *, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checking disabled."""
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **_CHECK_KW
-    )
-
-
 def slot_axis(num_slots: int, mesh: Mesh,
               rules: Optional[PartitionRules] = None):
     """Mesh axes the serving engine's slot dimension shards over.

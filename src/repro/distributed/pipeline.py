@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.partitioning import shard_map_unchecked
-
 PyTree = Any
 
 
@@ -44,10 +42,11 @@ def pipeline_forward(
     S = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
 
     @functools.partial(
-        shard_map_unchecked,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(None)),
         out_specs=P(None),
+        check_vma=False,
     )
     def pipe_fn(stage_params, microbatches):
         # stage_params leaves arrive as (1, ...) local slices

@@ -306,10 +306,7 @@ def run_chunk_events(
     )
     caps = _resolve_capacities(cfg, capacities)
 
-    if backend == "auto":
-        from repro.kernels import ops as _ops
-
-        backend = "fused" if _ops.on_tpu() else "jnp"
+    backend = resolve_backend(backend)
     if backend == "fused":
         return _run_chunk_fused(
             p, states, addrs, values, counts, cfg, act, caps, interpret,
@@ -366,6 +363,17 @@ def run_chunk_events(
     return list(fin_states), out_mem, out_spikes, events
 
 
+def resolve_backend(backend: str) -> str:
+    """The chunk backend ``backend`` names: ``"auto"`` is ``"fused"`` on
+    TPU and ``"jnp"`` elsewhere (where the fused kernel would run
+    interpreted); any other name is returned as given."""
+    if backend != "auto":
+        return backend
+    from repro.kernels import ops as _ops
+
+    return "fused" if _ops.on_tpu() else "jnp"
+
+
 def _resolve_capacities(
     cfg: snn.SNNConfig, capacities: Optional[Sequence[int]]
 ) -> List[int]:
@@ -387,8 +395,8 @@ def _run_chunk_fused(
 ):
     """Dispatch one chunk to the fused Pallas kernel.
 
-    The kernel consumes packed valid-first event tables via scalar
-    prefetch — exactly the staged format, so no extraction happens here.
+    The kernel reads packed valid-first event tables from SMEM —
+    exactly the staged format, so no extraction happens here.
     """
     from repro.kernels import ops
 

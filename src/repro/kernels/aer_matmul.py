@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 Array = jax.Array
 
 
@@ -181,7 +179,7 @@ def aer_spike_matmul_batched(
         functools.partial(_aer_batched_kernel, block_e=be, ne=ne),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Np), acc_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -228,7 +226,7 @@ def aer_spike_matmul(
         functools.partial(_aer_kernel, block_e=be, ne=ne),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, Np), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

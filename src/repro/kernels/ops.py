@@ -101,7 +101,7 @@ def snn_chunk(
     """Fused multi-timestep, multi-layer event-driven SNN chunk.
 
     One Pallas invocation advances the whole network ``Tc`` steps: layer-0
-    weight-row gathers driven by scalar-prefetched event lists (gated per
+    weight-row gathers driven by per-slot event lists in SMEM (gated per
     E-block on a non-silent predicate), membranes + refractory counters
     resident in VMEM scratch across all steps, hidden layers as gated
     in-VMEM matvecs.  ``layout="slot_major"`` consumes (B, Tc, C) tables

@@ -9,9 +9,11 @@ chunk runtime still stitched them together through HBM: per-step currents
 written out by the gather, read back by the LIF pass, and membrane state
 round-tripped between every step.  This kernel closes the loop:
 
-  - **per-step event lists ride in via scalar prefetch** (SMEM): the whole
-    (B, Tc, C) address/value/count table is available before the body runs,
-    so event addresses can drive dynamic weight-row indexing;
+  - **per-step event lists ride in through SMEM**: each slot program gets
+    its own (Tc*C) address and value rows as SMEM blocks indexed by the
+    slot, and the (B*Tc) per-step counts are scalar-prefetched, so event
+    addresses can drive dynamic weight-row indexing while scalar memory
+    stays bounded by one slot's chunk whatever the slot count;
   - **membrane potential and refractory counters live in VMEM scratch for
     all Tc steps** — HBM traffic for state is exactly one read of the
     incoming (B, N) slot states and one write of the outgoing ones,
@@ -39,7 +41,10 @@ and measured per-layer event counts all match to float32 tolerance
 
 Grid: (B,) — one program per batch slot; weights are broadcast blocks
 (index map constant in b) so each layer's slab is resident once, and slot
-programs are embarrassingly parallel.
+programs are embarrassingly parallel.  Every per-slot array carries the
+slot as a leading axis that its block squeezes (``None``), so the block's
+last two dims are whole array dims, as Mosaic's (8, 128) tiling rule
+requires at any slot count.
 """
 
 from __future__ import annotations
@@ -51,8 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams
 
 Array = jax.Array
 
@@ -69,9 +72,9 @@ def _round_up(x: int, m: int) -> int:
 
 def _chunk_kernel(
     act_ref,  # (B,) int32 prefetch: 1 = slot active, 0 = frozen
-    addr_ref,  # (B, Tc*C) int32 prefetch: layer-0 event addresses
-    val_ref,  # (B, Tc*C) f32 prefetch: signed event values (0 = pad)
-    cnt_ref,  # (B, Tc) int32 prefetch: valid events per step
+    cnt_ref,  # (B*Tc,) int32 prefetch: valid events per step
+    addr_ref,  # (1, Tc*C) int32 SMEM block: this slot's event addresses
+    val_ref,  # (1, Tc*C) f32 SMEM block: signed event values (0 = pad)
     *refs,
     num_layers: int,
     num_steps: int,
@@ -89,7 +92,7 @@ def _chunk_kernel(
     thrs = refs[3 * L : 4 * L]
     u0s = refs[4 * L : 5 * L]  # (1, NP_i) incoming slot state
     r0s = refs[5 * L : 6 * L]  # (1, NP_i) int32
-    mem_ref, spk_ref, ev_ref = refs[6 * L : 6 * L + 3]
+    mem_ref, spk_ref, ev_ref = refs[6 * L : 6 * L + 3]  # (Tc, NP) per slot
     ufins = refs[6 * L + 3 : 7 * L + 3]
     rfins = refs[7 * L + 3 : 8 * L + 3]
     u_scr = refs[8 * L + 3 : 9 * L + 3]  # VMEM-resident membranes
@@ -106,9 +109,7 @@ def _chunk_kernel(
         for i in range(L):
             ufins[i][...] = u0s[i][...]
             rfins[i][...] = r0s[i][...]
-        mem_ref[...] = jnp.broadcast_to(
-            u0s[L - 1][...][None], mem_ref.shape
-        )
+        mem_ref[...] = jnp.broadcast_to(u0s[L - 1][...], mem_ref.shape)
         spk_ref[...] = jnp.zeros_like(spk_ref)
         ev_ref[...] = jnp.zeros_like(ev_ref)
 
@@ -122,15 +123,15 @@ def _chunk_kernel(
 
         def step(t, _):
             # ---- layer 0: gated event-driven synaptic integration
-            n0 = cnt_ref[b, t]
+            n0 = cnt_ref[b * num_steps + t]
             base0 = t * cap
 
             def eblock(eb, acc):
                 base = base0 + eb * block_e
 
                 def gather(i, a):
-                    addr = addr_ref[b, base + i]
-                    v = val_ref[b, base + i]
+                    addr = addr_ref[0, base + i]
+                    v = val_ref[0, base + i]
                     row = ws[0][pl.ds(addr, 1), :].astype(jnp.float32)
                     return a + row * v
 
@@ -153,7 +154,9 @@ def _chunk_kernel(
             for i in range(L):
                 if i > 0:
                     # hidden layers: spike plane already VMEM-resident —
-                    # gated dense matvec (skip the product when silent)
+                    # gated dense matvec (skip the product when silent),
+                    # asked for at full float32 precision rather than at
+                    # the MXU's default contract precision
                     hcnt = jnp.sum(h)  # spikes are {0,1}: sum == nnz
                     ev_counts.append(hcnt)
                     w_i, b_i = ws[i], biases[i]
@@ -163,6 +166,7 @@ def _chunk_kernel(
                             jnp.dot(
                                 h,
                                 w_i[...],
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32,
                             )
                             + b_i[...]
@@ -192,12 +196,12 @@ def _chunk_kernel(
                     u_scr[i][...] = u_pre - thrs[i][...] * spk
                 h = spk
 
-            mem_ref[pl.ds(t, 1)] = u_scr[L - 1][...][None]
-            spk_ref[pl.ds(t, 1)] = h[None]
+            mem_ref[pl.ds(t, 1), :] = u_scr[L - 1][...]
+            spk_ref[pl.ds(t, 1), :] = h
             ev_row = jnp.zeros((1, _EV_PAD), jnp.float32)
             for i in range(L):
                 ev_row = jnp.where(lane == i, ev_counts[i], ev_row)
-            ev_ref[pl.ds(t, 1)] = ev_row[None]
+            ev_ref[pl.ds(t, 1), :] = ev_row
             return 0
 
         jax.lax.fori_loop(0, num_steps, step, 0)
@@ -246,10 +250,10 @@ def snn_chunk(
     Event lists must be packed valid-first with zero values on padding —
     exactly what ``events.runtime.step_events`` produces; the E-block gate
     relies on it.  Narrow dtypes (int16 addresses, int8 values — the
-    device-resident staging format) are widened here, on device, right
-    before prefetch.  ``layout="slot_major"`` accepts (B, Tc, C) tables —
+    device-resident staging format) are widened here, on device, before
+    the kernel reads them.  ``layout="slot_major"`` accepts (B, Tc, C) tables —
     the per-slot ring-buffer layout — and skips the transpose the
-    time-major layout needs to build the flat per-slot prefetch stream.
+    time-major layout needs to build the flat per-slot event stream.
     """
     L = len(weights)
     assert L <= _EV_PAD, "event-count lane supports at most 128 layers"
@@ -291,25 +295,40 @@ def snn_chunk(
                 constant_values=_PAD_THRESHOLD,
             )[None, :]
         )
-        u0p.append(jnp.pad(u0[i].astype(jnp.float32), ((0, 0), (0, pn))))
-        r0p.append(jnp.pad(r0[i].astype(jnp.int32), ((0, 0), (0, pn))))
+        u0p.append(
+            jnp.pad(u0[i].astype(jnp.float32), ((0, 0), (0, pn)))[:, None]
+        )
+        r0p.append(
+            jnp.pad(r0[i].astype(jnp.int32), ((0, 0), (0, pn)))[:, None]
+        )
 
-    # prefetch tables: flat per-slot event streams + per-step counts
+    # flat per-slot event streams + per-step counts
     if layout == "slot_major":
-        addrs_f = addrs.reshape(B, Tc * Cp).astype(jnp.int32)
-        values_f = values.reshape(B, Tc * Cp).astype(jnp.float32)
-        counts_f = counts.astype(jnp.int32)
+        addrs_f = addrs.reshape(B, 1, Tc * Cp).astype(jnp.int32)
+        values_f = values.reshape(B, 1, Tc * Cp).astype(jnp.float32)
+        counts_f = counts.reshape(B * Tc).astype(jnp.int32)
     else:
         addrs_f = (
-            addrs.transpose(1, 0, 2).reshape(B, Tc * Cp).astype(jnp.int32)
+            addrs.transpose(1, 0, 2).reshape(B, 1, Tc * Cp).astype(jnp.int32)
         )
         values_f = (
-            values.transpose(1, 0, 2).reshape(B, Tc * Cp).astype(jnp.float32)
+            values.transpose(1, 0, 2)
+            .reshape(B, 1, Tc * Cp)
+            .astype(jnp.float32)
         )
-        counts_f = counts.transpose(1, 0).astype(jnp.int32)
+        counts_f = counts.transpose(1, 0).reshape(B * Tc).astype(jnp.int32)
     act = (jnp.asarray(active) != 0).astype(jnp.int32)
 
-    in_specs = []
+    # one slot's (Tc*Cp) event rows per grid step: SMEM holds two such
+    # blocks (double-buffered) per table, independent of B
+    in_specs = [
+        pl.BlockSpec(
+            (None, 1, Tc * Cp),
+            lambda b, *_: (b, 0, 0),
+            memory_space=pltpu.SMEM,
+        )
+        for _ in range(2)
+    ]
     for i in range(L):
         # index map constant in b: each slab is resident once, shared by
         # every slot program
@@ -321,35 +340,39 @@ def snn_chunk(
             in_specs.append(
                 pl.BlockSpec((1, np_out[i]), lambda b, *_: (0, 0))
             )
+    def slot_block(*dims):
+        # leading slot axis squeezed: the kernel sees the trailing dims
+        return pl.BlockSpec(
+            (None, *dims), lambda b, *_: (b,) + (0,) * len(dims)
+        )
+
     for group in (u0p, r0p):
         for i in range(L):
-            in_specs.append(
-                pl.BlockSpec((1, np_out[i]), lambda b, *_: (b, 0))
-            )
+            in_specs.append(slot_block(1, np_out[i]))
 
     npl = np_out[-1]
     out_specs = [
-        pl.BlockSpec((Tc, 1, npl), lambda b, *_: (0, b, 0)),  # mem
-        pl.BlockSpec((Tc, 1, npl), lambda b, *_: (0, b, 0)),  # spikes
-        pl.BlockSpec((Tc, 1, _EV_PAD), lambda b, *_: (0, b, 0)),  # events
+        slot_block(Tc, npl),  # mem
+        slot_block(Tc, npl),  # spikes
+        slot_block(Tc, _EV_PAD),  # events
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((Tc, B, npl), jnp.float32),
-        jax.ShapeDtypeStruct((Tc, B, npl), jnp.float32),
-        jax.ShapeDtypeStruct((Tc, B, _EV_PAD), jnp.float32),
+        jax.ShapeDtypeStruct((B, Tc, npl), jnp.float32),
+        jax.ShapeDtypeStruct((B, Tc, npl), jnp.float32),
+        jax.ShapeDtypeStruct((B, Tc, _EV_PAD), jnp.float32),
     ]
     for i in range(L):  # final membranes
-        out_specs.append(pl.BlockSpec((1, np_out[i]), lambda b, *_: (b, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((B, np_out[i]), jnp.float32))
+        out_specs.append(slot_block(1, np_out[i]))
+        out_shape.append(jax.ShapeDtypeStruct((B, 1, np_out[i]), jnp.float32))
     for i in range(L):  # final refractory counters
-        out_specs.append(pl.BlockSpec((1, np_out[i]), lambda b, *_: (b, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((B, np_out[i]), jnp.int32))
+        out_specs.append(slot_block(1, np_out[i]))
+        out_shape.append(jax.ShapeDtypeStruct((B, 1, np_out[i]), jnp.int32))
 
     scratch_shapes = [pltpu.VMEM((1, np_out[i]), jnp.float32) for i in range(L)]
     scratch_shapes += [pltpu.VMEM((1, np_out[i]), jnp.int32) for i in range(L)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=2,
         grid=(B,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -369,19 +392,21 @@ def snn_chunk(
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(act, addrs_f, values_f, counts_f, *ws, *bs, *bet, *thr, *u0p, *r0p)
+    )(act, counts_f, addrs_f, values_f, *ws, *bs, *bet, *thr, *u0p, *r0p)
 
     mem, spk, ev = results[0], results[1], results[2]
-    u_fin = tuple(
-        results[3 + i][:, : outs[i]] for i in range(L)
-    )
-    r_fin = tuple(
-        results[3 + L + i][:, : outs[i]] for i in range(L)
-    )
+    u_fin = tuple(results[3 + i][:, 0, : outs[i]] for i in range(L))
+    r_fin = tuple(results[3 + L + i][:, 0, : outs[i]] for i in range(L))
     n_last = outs[-1]
-    events = ev[:, :, :L].transpose(0, 2, 1)  # (Tc, L, B)
-    return mem[:, :, :n_last], spk[:, :, :n_last], events, u_fin, r_fin
+    events = ev[:, :, :L].transpose(1, 2, 0)  # (Tc, L, B)
+    return (
+        mem[:, :, :n_last].transpose(1, 0, 2),
+        spk[:, :, :n_last].transpose(1, 0, 2),
+        events,
+        u_fin,
+        r_fin,
+    )

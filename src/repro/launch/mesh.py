@@ -18,15 +18,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    """jax-version shim: ``AxisType`` (and ``make_mesh``'s ``axis_types``
-    kwarg) only exist on newer jax; older versions default to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(
@@ -42,7 +34,9 @@ def make_production_mesh(
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     assert axes is not None and len(axes) == len(shape)
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_host_mesh(model: int = 1):
@@ -50,5 +44,5 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     data = max(n // model, 1)
     return jax.make_mesh(
-        (data, model), ("data", "model"), **_mesh_kwargs(2)
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
     )

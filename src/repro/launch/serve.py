@@ -56,6 +56,7 @@ import jax
 import numpy as np
 
 import repro.configs as configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serving.engine import Request, ServeEngine
 
@@ -87,7 +88,8 @@ def _serve_lm(args) -> None:
     dt = time.time() - t0
     n = sum(len(o) for o in outs)
     print(f"{args.arch}: served {len(reqs)} reqs / {n} tokens in {dt:.2f}s "
-          f"({n/dt:.1f} tok/s on CPU, quant={cfg.quant})")
+          f"({n/dt:.1f} tok/s on {jax.devices()[0].device_kind}, "
+          f"quant={cfg.quant})")
 
 
 def _serve_snn(args) -> None:
@@ -375,12 +377,11 @@ def _serve_snn(args) -> None:
         )
     if profile is not None:
         if profile.error:
-            print(f"  jax.profiler capture FAILED: {profile.error}")
-        else:
-            print(
-                f"  jax.profiler capture ({args.profile_ticks} "
-                f"steady-state ticks) -> {args.profile_dir}"
-            )
+            raise SystemExit(f"jax.profiler capture FAILED: {profile.error}")
+        print(
+            f"  jax.profiler capture ({args.profile_ticks} "
+            f"steady-state ticks) -> {args.profile_dir}"
+        )
 
 
 def main(argv=None):
@@ -470,6 +471,7 @@ def main(argv=None):
     ap.add_argument("--profile-dir", default="/tmp/snn-jax-profile",
                     help="output directory for --profile-ticks")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.snn:
         _serve_snn(args)

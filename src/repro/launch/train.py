@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 import repro.configs as configs
 from repro.data.tokens import MarkovTokenStream, TokenStreamConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import CLIP_EMBED_DIM, Model
 from repro.optim import adamw, chain_clip, warmup_cosine
@@ -154,6 +155,7 @@ def main(argv=None):
                     help="write the per-window time series (counter "
                          "deltas, windowed rates) as JSONL")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.snn_events:
         _train_snn_events(args)

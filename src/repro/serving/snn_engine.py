@@ -144,7 +144,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.manager import (
     CheckpointCorruptError,
@@ -172,6 +172,17 @@ _FAULT_NAMES = {
     FAULT_RING_CORRUPT: "ring_corrupt",
     FAULT_CAPACITY_OVERFLOW: "capacity_overflow",
 }
+
+
+def _set_slot(x: Array, slot, value) -> Array:
+    """``x`` with row ``slot`` of its leading (slot) axis set to ``value``.
+
+    An elementwise select rather than a scatter, so it keeps the layout of
+    a slot-sharded ``x`` and needs no output-sharding annotation on an
+    Explicit mesh."""
+    hit = jnp.arange(x.shape[0], dtype=jnp.int32) == slot
+    hit = hit.reshape((-1,) + (1,) * (x.ndim - 1))
+    return jnp.where(hit, jnp.asarray(value, x.dtype), x)
 
 
 def fault_code_names(code: int) -> str:
@@ -321,8 +332,22 @@ class SNNStreamEngine:
         # prepare (fake-quantize) once at init — the original loop re-ran
         # the full weight-set quantization inside every chunk execution
         self._prepared = jax.device_put(runtime.prepare_params(params, cfg))
-        self.backend = backend
+        # "auto" resolves here, once, so health() and snapshots name the
+        # kernel that actually runs
+        self.backend = runtime.resolve_backend(backend)
         self.mesh = mesh
+        # every slot-indexed array (states, rings, meta) lives split over
+        # the mesh's slot axes; None keeps it on the default device
+        self._slot_sharding = (
+            None
+            if mesh is None
+            else NamedSharding(mesh, P(partitioning.slot_axis(num_slots, mesh)))
+        )
+        if mesh is not None and preempt:
+            raise ValueError(
+                "preempt=True moves single slot rows between host and "
+                "device and is not supported on a slot-sharded mesh"
+            )
         self.pipeline_depth = max(0, int(pipeline_depth))
         self.capacities = (
             tuple(int(c) for c in capacities)
@@ -339,7 +364,6 @@ class SNNStreamEngine:
         # urgency arrival may park the loosest resident window
         self.preempt = bool(preempt)
         self._snap_index = 0  # snapshot_auto rotation counter
-        self._backend_active = backend
         self._supervisor = ChunkSupervisor(
             retry or RetryPolicy(),
             on_retry=lambda n: self._m_retries.inc(n),
@@ -351,7 +375,7 @@ class SNNStreamEngine:
         self._addr_dtype = aer.addr_dtype_for(cfg.layer_sizes[0])
         self._ring_steps = max(int(cfg.num_steps), chunk_steps)
 
-        self._chunk, self._chunk_nodonate = self._build_chunk(backend)
+        self._chunk, self._chunk_nodonate = self._build_chunk(self.backend)
         # compile-site allowlist: one cold-start compile of the fresh
         # chunk; _grow_ring and demotion bump/reset it (known sites)
         self._chunk_compiles_expected = 1
@@ -513,13 +537,14 @@ class SNNStreamEngine:
         carry over unchanged.
         """
         slot = partitioning.slot_axis(num_slots, mesh)
-        return partitioning.shard_map_unchecked(
+        return jax.shard_map(
             chunk_fn,
-            mesh,
+            mesh=mesh,
             # (params, states, ring, meta) — P(slot) prefixes shard the
             # leading slot axis of every states/ring/meta leaf
             in_specs=(P(), P(slot), P(slot), P(slot)),
             out_specs=(P(slot), P(slot), P(slot)),
+            check_vma=False,
         )
 
     # ------------------------------------------------- device admission
@@ -538,15 +563,17 @@ class SNNStreamEngine:
         def stage(ring, meta, train, slot):
             T = train.shape[0]
             table = runtime.encode_step_table(train, C, addr_dtype=adt)
+            # the slot's whole ring row is rewritten, zero past step T
+            pad = ring["counts"].shape[1] - T
             ring = {
-                "addrs": jax.lax.dynamic_update_slice(
-                    ring["addrs"], table.addrs[None], (slot, 0, 0)
+                "addrs": _set_slot(
+                    ring["addrs"], slot, jnp.pad(table.addrs, ((0, pad), (0, 0)))
                 ),
-                "values": jax.lax.dynamic_update_slice(
-                    ring["values"], table.values[None], (slot, 0, 0)
+                "values": _set_slot(
+                    ring["values"], slot, jnp.pad(table.values, ((0, pad), (0, 0)))
                 ),
-                "counts": jax.lax.dynamic_update_slice(
-                    ring["counts"], table.counts[None], (slot, 0)
+                "counts": _set_slot(
+                    ring["counts"], slot, jnp.pad(table.counts, (0, pad))
                 ),
             }
             if fault_checks:
@@ -562,10 +589,10 @@ class SNNStreamEngine:
             else:
                 fcode = jnp.int32(0)
             meta = {
-                "done": meta["done"].at[slot].set(0),
-                "total": meta["total"].at[slot].set(T),
-                "admit": meta["admit"].at[slot].set(1),
-                "fault": meta["fault"].at[slot].set(fcode),
+                "done": _set_slot(meta["done"], slot, 0),
+                "total": _set_slot(meta["total"], slot, T),
+                "admit": _set_slot(meta["admit"], slot, 1),
+                "fault": _set_slot(meta["fault"], slot, fcode),
             }
             return ring, meta
 
@@ -591,11 +618,17 @@ class SNNStreamEngine:
         # [0, ring_steps]
         S, Tc, C = self.S, self.Tc, self.C
         R = ring_steps + Tc
-        return {
+        return self._on_slots({
             "addrs": jnp.zeros((S, R, C), self._addr_dtype),
             "values": jnp.zeros((S, R, C), jnp.int8),
             "counts": jnp.zeros((S, R), jnp.int32),
-        }
+        })
+
+    def _on_slots(self, tree):
+        """Place a pytree of slot-leading arrays: split over the mesh's
+        slot axes when the engine has a mesh, else on the default
+        device."""
+        return jax.device_put(tree, self._slot_sharding)
 
     def _grow_ring(self, T: int) -> None:
         """Grow the rings to hold a T-step train (T > current capacity).
@@ -604,11 +637,11 @@ class SNNStreamEngine:
         trains survive.  The chunk function recompiles once for the new
         ring shape (shapes are static thereafter).
         """
-        old, r_old = self._ring, self._ring_steps + self.Tc
+        grow = int(T) - self._ring_steps
         self._ring_steps = int(T)
-        new = self._alloc_ring(self._ring_steps)
         self._ring = {
-            k: new[k].at[:, :r_old].set(old[k]) for k in new
+            k: jnp.pad(v, ((0, 0), (0, grow)) + ((0, 0),) * (v.ndim - 2))
+            for k, v in self._ring.items()
         }
         # a larger ring is a new chunk input shape: one more compile is
         # a known site, not a steady-state recompile
@@ -816,7 +849,7 @@ class SNNStreamEngine:
             "quarantined_total": quarantined,
             "backend_demotions": demoted,
             "chunk_retries": retries,
-            "backend": self._backend_active,
+            "backend": self.backend,
         }
 
     def windowed_miss_rate(self, window_s: Optional[float] = 1.0) -> float:
@@ -832,14 +865,14 @@ class SNNStreamEngine:
     # ------------------------------------------------------------- state
     def _reset_all(self) -> None:
         cfg, S = self.cfg, self.S
-        self._states = runtime.init_states(cfg, S)
+        self._states = self._on_slots(runtime.init_states(cfg, S))
         self._ring = self._alloc_ring(self._ring_steps)
-        self._meta = {
+        self._meta = self._on_slots({
             "done": jnp.zeros((S,), jnp.int32),
             "total": jnp.zeros((S,), jnp.int32),
             "admit": jnp.zeros((S,), jnp.int32),
             "fault": jnp.zeros((S,), jnp.int32),
-        }
+        })
         self._slot_req = [None] * S  # request id per slot
         self._slot_parked = [False] * S  # admitted from the parked list
         self._slot_done = np.zeros(S, np.int64)  # steps dispatched
@@ -1281,13 +1314,12 @@ class SNNStreamEngine:
         see ``repro.faults.supervisor``."""
         def attempt():
             if self.injector is not None:
-                self.injector.maybe_raise(self._backend_active)
+                self.injector.maybe_raise(self.backend)
             return self._chunk(
                 self._prepared, self._states, self._ring, self._meta
             )
 
         def demote():
-            self._backend_active = "jnp"
             self.backend = "jnp"
             self._chunk, self._chunk_nodonate = self._build_chunk("jnp")
             # fresh jit object: its cold-start compile is a known site
@@ -1297,8 +1329,8 @@ class SNNStreamEngine:
 
         return self._supervisor.call(
             attempt,
-            backend=self._backend_active,
-            demote=demote if self._backend_active == "fused" else None,
+            backend=self.backend,
+            demote=demote if self.backend == "fused" else None,
         )
 
     def _retire(self) -> List[int]:
@@ -1733,7 +1765,7 @@ class SNNStreamEngine:
                 "ring_steps": self._ring_steps,
                 "layer_sizes": list(self.cfg.layer_sizes),
             },
-            "backend": self._backend_active,
+            "backend": self.backend,
             "tick_index": self._tick_index,
             "seq": self._seq,
             "next_rid": self._next_rid,
@@ -1798,21 +1830,20 @@ class SNNStreamEngine:
             self._chunk_compiles_expected += 1
         now = time.perf_counter()
         try:
-            self._states = [
+            self._states = self._on_slots([
                 neuron.NeuronState(
-                    u=jax.device_put(arrays[f"state{i}_u"]),
-                    refrac=jax.device_put(arrays[f"state{i}_refrac"]),
+                    u=arrays[f"state{i}_u"],
+                    refrac=arrays[f"state{i}_refrac"],
                 )
                 for i in range(len(self._states))
-            ]
-            self._ring = {
-                k: jax.device_put(arrays[f"ring_{k}"])
-                for k in ("addrs", "values", "counts")
-            }
-            self._meta = {
-                k: jax.device_put(arrays[f"meta_{k}"])
+            ])
+            self._ring = self._on_slots({
+                k: arrays[f"ring_{k}"] for k in ("addrs", "values", "counts")
+            })
+            self._meta = self._on_slots({
+                k: arrays[f"meta_{k}"]
                 for k in ("done", "total", "admit", "fault")
-            }
+            })
             self._rng = jax.device_put(arrays["rng_key"])
             self._slot_done = arrays["slot_done"].astype(np.int64)
             self._slot_retired = arrays["slot_retired"].astype(np.int64)
@@ -2137,7 +2168,7 @@ class SNNStreamEngine:
             ],
             "inflight": len(self._inflight),
             "pending_results": len(self._pending_results),
-            "backend": self._backend_active,
+            "backend": self.backend,
             "slots": [
                 {
                     "slot": s,
@@ -2245,25 +2276,25 @@ class SNNStreamEngine:
         it, without mutating the live engine."""
         if len(trains) != self.S:
             raise ValueError(f"need {self.S} trains, got {len(trains)}")
-        states = runtime.init_states(self.cfg, self.S)
+        states = self._on_slots(runtime.init_states(self.cfg, self.S))
         ring = self._alloc_ring(
             max(self._ring_steps, max(t.shape[0] for t in trains))
         )
-        meta = {
+        meta = self._on_slots({
             "done": jnp.zeros((self.S,), jnp.int32),
             "total": jnp.asarray(
                 [t.shape[0] for t in trains], jnp.int32
             ),
             "admit": jnp.zeros((self.S,), jnp.int32),
             "fault": jnp.zeros((self.S,), jnp.int32),
-        }
+        })
         for s, t in enumerate(trains):
             train = jax.device_put(np.asarray(t, np.float32))
             # same slot dtype as _admit(): a bare python int would hit a
             # separate (weak-typed) jit cache entry and recompile
             slot = jax.device_put(np.int32(s))
             ring, meta = self._admit_spikes_fn(ring, meta, train, slot)
-        meta = {**meta, "admit": jnp.zeros((self.S,), jnp.int32)}
+        meta = {**meta, "admit": self._on_slots(jnp.zeros((self.S,), jnp.int32))}
         return self._prepared, states, ring, meta
 
     def chunk_for_timing(self):
