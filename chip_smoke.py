@@ -38,6 +38,13 @@ import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parent
 NUM_REQUESTS = 16
+# one seed for the random weights and the numpy spike trains
+SEED = 0
+# Gain on the output layer's random weights and bias.  At the init scale
+# the two output neurons hardly fire (one spike in 16 windows), so output
+# spike counts would test nothing and every prediction would come from
+# the membrane tie-break; at this gain both fire several times a window.
+OUT_GAIN = 8.0
 # How far spike counts may differ from the reference over a 25-step
 # window: OUT_TOL per output neuron, HIDDEN_TOL for a request's whole
 # hidden layer (~1000 spikes from 512 neurons).  Both sides compute in
@@ -77,6 +84,21 @@ def collision_trains(num: int, hw: int, steps: int, seed: int):
         (rng.random((steps, flat.shape[1])) < img[None, :]).astype(np.float32)
         for img in flat
     ]
+
+
+def smoke_params(cfg):
+    """Random weights from ``SEED``, the output layer scaled by
+    ``OUT_GAIN``."""
+    import jax
+
+    from repro.core import snn
+
+    params = snn.init_params(jax.random.PRNGKey(SEED), cfg)
+    last = f"layer{len(cfg.layer_sizes) - 2}"
+    out = {**params[last]}
+    out["w"] = out["w"] * OUT_GAIN
+    out["b"] = out["b"] * OUT_GAIN
+    return {**params, last: out}
 
 
 def reference(params, cfg, trains):
@@ -240,7 +262,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the slot-sharded 4-chip phase")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     if not (ROOT / "src" / "repro").is_dir():
@@ -262,13 +283,13 @@ def main(argv=None) -> int:
     print(f"device: {d0.device_kind} x{len(devices)} ({d0.platform})")
 
     from repro.configs.collision_snn import CONFIG as cfg
-    from repro.core import snn
 
     print(f"config: layers={cfg.layer_sizes} T={cfg.num_steps} "
           f"neuron={cfg.neuron_kind} reset={cfg.reset}")
-    params = snn.init_params(jax.random.PRNGKey(args.seed), cfg)
+    params = smoke_params(cfg)
+    print(f"weights: seed {SEED}, output layer gain {OUT_GAIN:g}")
     hw = int(round(cfg.layer_sizes[0] ** 0.5))
-    trains = collision_trains(NUM_REQUESTS, hw, cfg.num_steps, args.seed)
+    trains = collision_trains(NUM_REQUESTS, hw, cfg.num_steps, SEED)
     rate = float(np.mean([t.mean() for t in trains]))
     print(f"requests: {len(trains)} spike trains, mean input rate {rate:.4f}")
 

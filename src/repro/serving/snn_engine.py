@@ -174,15 +174,56 @@ _FAULT_NAMES = {
 }
 
 
-def _set_slot(x: Array, slot, value) -> Array:
-    """``x`` with row ``slot`` of its leading (slot) axis set to ``value``.
+def _slot_row_writer(mesh, num_slots: int):
+    """``write(x, slot, value)``: ``x`` with ``value`` written at the
+    front of row ``slot`` of its leading (slot) axis, i.e.
+    ``x[slot, :value.shape[0], ...] = value``.
 
-    An elementwise select rather than a scatter, so it keeps the layout of
-    a slot-sharded ``x`` and needs no output-sharding annotation on an
+    Without a mesh this is one ``dynamic_update_slice``.  On a mesh each
+    shard runs the same update on its local slot block and keeps its own
+    row where ``slot`` lies in another shard's block, so the write touches
+    one row on every layout and needs no sharding annotation on an
     Explicit mesh."""
-    hit = jnp.arange(x.shape[0], dtype=jnp.int32) == slot
-    hit = hit.reshape((-1,) + (1,) * (x.ndim - 1))
-    return jnp.where(hit, jnp.asarray(value, x.dtype), x)
+
+    def local(x, slot, value):
+        start = (slot,) + (0,) * (x.ndim - 1)
+        return jax.lax.dynamic_update_slice(
+            x, jnp.asarray(value, x.dtype)[None], start
+        )
+
+    if mesh is None:
+        return local
+    axis = partitioning.slot_axis(num_slots, mesh)
+
+    def shard(x, slot, value):
+        n = x.shape[0]  # slots in this shard's block
+        i = slot - jax.lax.axis_index(axis) * n
+        mine = (i >= 0) & (i < n)
+        start = (jnp.clip(i, 0, n - 1),) + (0,) * (x.ndim - 1)
+        row = jnp.asarray(value, x.dtype)[None]
+        old = jax.lax.dynamic_slice(x, start, row.shape)
+        return jax.lax.dynamic_update_slice(
+            x, jnp.where(mine, row, old), start
+        )
+
+    return jax.shard_map(
+        shard,
+        mesh=mesh,
+        in_specs=(P(axis), P(), P()),
+        out_specs=P(axis),
+        check_vma=False,
+    )
+
+
+def _host_row(x: Array, s: int) -> np.ndarray:
+    """Row ``s`` of ``x``'s leading (slot) axis, on the host, read from
+    the one shard that holds it: no gather, so a slot-sharded ``x`` on an
+    Explicit mesh needs no sharding annotation."""
+    for shard in x.addressable_shards:
+        lo, hi, _ = shard.index[0].indices(x.shape[0])
+        if lo <= s < hi:
+            return np.asarray(jax.device_get(shard.data[s - lo]))
+    raise IndexError(f"row {s} of {x.shape} is on no addressable shard")
 
 
 def fault_code_names(code: int) -> str:
@@ -331,23 +372,27 @@ class SNNStreamEngine:
         self._make_instruments(trace_capacity, timeseries_capacity)
         # prepare (fake-quantize) once at init — the original loop re-ran
         # the full weight-set quantization inside every chunk execution
-        self._prepared = jax.device_put(runtime.prepare_params(params, cfg))
+        self._prepared = jax.device_put(
+            runtime.prepare_params(params, cfg),
+            None if mesh is None else NamedSharding(mesh, P()),
+        )
         # "auto" resolves here, once, so health() and snapshots name the
         # kernel that actually runs
         self.backend = runtime.resolve_backend(backend)
         self.mesh = mesh
         # every slot-indexed array (states, rings, meta) lives split over
         # the mesh's slot axes; None keeps it on the default device
-        self._slot_sharding = (
-            None
-            if mesh is None
-            else NamedSharding(mesh, P(partitioning.slot_axis(num_slots, mesh)))
+        self._slot_axis = (
+            None if mesh is None else partitioning.slot_axis(num_slots, mesh)
         )
-        if mesh is not None and preempt:
-            raise ValueError(
-                "preempt=True moves single slot rows between host and "
-                "device and is not supported on a slot-sharded mesh"
-            )
+        # one-row writes into slot-indexed arrays (admission, park and
+        # resume); _write_row holds the eager, buffer-donating form for
+        # each array rank
+        self._row_writer = _slot_row_writer(mesh, num_slots)
+        self._write_row = {
+            nd: self._slot_jit(self._row_writer, nd, donate_argnums=0)
+            for nd in (1, 2, 3)
+        }
         self.pipeline_depth = max(0, int(pipeline_depth))
         self.capacities = (
             tuple(int(c) for c in capacities)
@@ -519,9 +564,38 @@ class SNNStreamEngine:
             body = _chunk_fn
         else:
             body = self._shard_over_slots(_chunk_fn, mesh, num_slots)
+        # output ranks: states (S, N) rows, (S,) meta; stats go to the
+        # host and are never fed back, so their prefix needs no full rank
+        ranks = (
+            [neuron.NeuronState(u=2, refrac=2)] * cfg.num_layers, 1, 1
+        )
         # states + metadata are donated: the tick loop threads them
         # through the compiled chunk without ever copying them back out
-        return jax.jit(body, donate_argnums=(1, 3)), jax.jit(body)
+        return (
+            self._slot_jit(body, ranks, donate_argnums=(1, 3)),
+            self._slot_jit(body, ranks),
+        )
+
+    def _slot_sharding(self, ndim: int) -> NamedSharding:
+        """The one sharding of a rank-``ndim`` slot-leading array on the
+        mesh: split on the slot axis, full rank.  Jitted outputs on a
+        mesh may carry an equivalent spec of another rank (``P(slot)``
+        for ``P(slot, None)``), which the jit cache treats as a new
+        input sharding, so every producer of a slot array states this
+        one."""
+        return NamedSharding(
+            self.mesh, P(self._slot_axis, *(None,) * (ndim - 1))
+        )
+
+    def _slot_jit(self, fn, ranks, **kw):
+        """``jax.jit(fn)`` whose outputs, on a mesh, carry
+        ``_slot_sharding`` of the ranks in ``ranks`` (a pytree prefix of
+        the outputs)."""
+        if self.mesh is None:
+            return jax.jit(fn, **kw)
+        return jax.jit(
+            fn, out_shardings=jax.tree.map(self._slot_sharding, ranks), **kw
+        )
 
     @staticmethod
     def _shard_over_slots(chunk_fn, mesh, num_slots: int):
@@ -553,28 +627,22 @@ class SNNStreamEngine:
         and write it into the slot's ring, updating device metadata.
 
         Ring and metadata buffers are donated — each admission rewrites
-        them in place (device-side), costing one small H2D upload (the
-        train or the raw image) and zero host round-trips.
+        the slot's first T ring steps and its metadata in place
+        (device-side, on every mesh shard alike), costing one small H2D
+        upload (the train or the raw image) and zero host round-trips.
         """
         C = self.C
         adt = self._addr_dtype
         fault_checks = self.fault_checks
+        write = self._row_writer
 
         def stage(ring, meta, train, slot):
             T = train.shape[0]
             table = runtime.encode_step_table(train, C, addr_dtype=adt)
-            # the slot's whole ring row is rewritten, zero past step T
-            pad = ring["counts"].shape[1] - T
             ring = {
-                "addrs": _set_slot(
-                    ring["addrs"], slot, jnp.pad(table.addrs, ((0, pad), (0, 0)))
-                ),
-                "values": _set_slot(
-                    ring["values"], slot, jnp.pad(table.values, ((0, pad), (0, 0)))
-                ),
-                "counts": _set_slot(
-                    ring["counts"], slot, jnp.pad(table.counts, (0, pad))
-                ),
+                "addrs": write(ring["addrs"], slot, table.addrs),
+                "values": write(ring["values"], slot, table.values),
+                "counts": write(ring["counts"], slot, table.counts),
             }
             if fault_checks:
                 # capacity overflow: a step with more nonzero inputs
@@ -589,10 +657,10 @@ class SNNStreamEngine:
             else:
                 fcode = jnp.int32(0)
             meta = {
-                "done": _set_slot(meta["done"], slot, 0),
-                "total": _set_slot(meta["total"], slot, T),
-                "admit": _set_slot(meta["admit"], slot, 1),
-                "fault": _set_slot(meta["fault"], slot, fcode),
+                "done": write(meta["done"], slot, 0),
+                "total": write(meta["total"], slot, T),
+                "admit": write(meta["admit"], slot, 1),
+                "fault": write(meta["fault"], slot, fcode),
             }
             return ring, meta
 
@@ -605,11 +673,13 @@ class SNNStreamEngine:
             train = coding.rate_encode(key, image, T)
             return stage(ring, meta, train, slot)
 
-        self._admit_spikes_fn = jax.jit(
-            admit_spikes, donate_argnums=(0, 1)
+        # output ranks: the (S, R, C) / (S, R) ring and (S,) meta
+        ranks = ({"addrs": 3, "values": 3, "counts": 2}, 1)
+        self._admit_spikes_fn = self._slot_jit(
+            admit_spikes, ranks, donate_argnums=(0, 1)
         )
-        self._admit_image_fn = jax.jit(
-            admit_image, donate_argnums=(0, 1), static_argnames=("T",)
+        self._admit_image_fn = self._slot_jit(
+            admit_image, ranks, donate_argnums=(0, 1), static_argnames=("T",)
         )
 
     def _alloc_ring(self, ring_steps: int) -> Dict[str, Array]:
@@ -624,11 +694,22 @@ class SNNStreamEngine:
             "counts": jnp.zeros((S, R), jnp.int32),
         })
 
+    def _put_row(self, x: Array, s: int, value) -> Array:
+        """Eager one-row write (``_slot_row_writer``) into slot-indexed
+        ``x``, whose buffer is donated."""
+        return self._write_row[x.ndim](x, np.int32(s), value)
+
     def _on_slots(self, tree):
         """Place a pytree of slot-leading arrays: split over the mesh's
         slot axes when the engine has a mesh, else on the default
-        device."""
-        return jax.device_put(tree, self._slot_sharding)
+        device.  On a mesh each array gets ``_slot_sharding``, the
+        sharding every jitted producer of slot arrays gives its outputs,
+        so the chunk sees one input sharding and compiles once."""
+        if self.mesh is None:
+            return jax.device_put(tree)
+        return jax.tree.map(
+            lambda x: jax.device_put(x, self._slot_sharding(x.ndim)), tree
+        )
 
     def _grow_ring(self, T: int) -> None:
         """Grow the rings to hold a T-step train (T > current capacity).
@@ -639,10 +720,10 @@ class SNNStreamEngine:
         """
         grow = int(T) - self._ring_steps
         self._ring_steps = int(T)
-        self._ring = {
+        self._ring = self._on_slots({
             k: jnp.pad(v, ((0, 0), (0, grow)) + ((0, 0),) * (v.ndim - 2))
             for k, v in self._ring.items()
-        }
+        })
         # a larger ring is a new chunk input shape: one more compile is
         # a known site, not a steady-state recompile
         self._chunk_compiles_expected += 1
@@ -1549,22 +1630,11 @@ class SNNStreamEngine:
             "abs_deadline": self._slot_deadline[s],
             "t_submit": float(self._slot_submit_t[s]),
             "t_admit": float(self._slot_admit_t[s]),
-            "u": [
-                np.asarray(jax.device_get(st.u[s])) for st in self._states
-            ],
-            "refrac": [
-                np.asarray(jax.device_get(st.refrac[s]))
-                for st in self._states
-            ],
-            "ring_addrs": np.asarray(
-                jax.device_get(self._ring["addrs"][s])
-            ),
-            "ring_values": np.asarray(
-                jax.device_get(self._ring["values"][s])
-            ),
-            "ring_counts": np.asarray(
-                jax.device_get(self._ring["counts"][s])
-            ),
+            "u": [_host_row(st.u, s) for st in self._states],
+            "refrac": [_host_row(st.refrac, s) for st in self._states],
+            "ring_addrs": _host_row(self._ring["addrs"], s),
+            "ring_values": _host_row(self._ring["values"], s),
+            "ring_counts": _host_row(self._ring["counts"], s),
             "counts": self._slot_counts[s].copy(),
             "memsum": self._slot_memsum[s].copy(),
             "events": self._slot_events[s].copy(),
@@ -1573,10 +1643,7 @@ class SNNStreamEngine:
         # free the slot: total=0 makes the next chunk take nothing from
         # it; the stale device state is dead weight until overwritten
         self._meta = {
-            "done": self._meta["done"].at[s].set(0),
-            "total": self._meta["total"].at[s].set(0),
-            "admit": self._meta["admit"].at[s].set(0),
-            "fault": self._meta["fault"].at[s].set(0),
+            k: self._put_row(v, s, np.int32(0)) for k, v in self._meta.items()
         }
         self._slot_req[s] = None
         self._slot_parked[s] = False
@@ -1602,32 +1669,24 @@ class SNNStreamEngine:
             # restore onto a smaller-ring engine; grow back so the
             # stored row fits (one allowlisted recompile)
             self._grow_ring(rec["ring_steps"])
-        r = rec["ring_addrs"].shape[0]
+        put = self._put_row
         self._states = [
             neuron.NeuronState(
-                u=st.u.at[s].set(jax.device_put(rec["u"][i])),
-                refrac=st.refrac.at[s].set(
-                    jax.device_put(rec["refrac"][i])
-                ),
+                u=put(st.u, s, rec["u"][i]),
+                refrac=put(st.refrac, s, rec["refrac"][i]),
             )
             for i, st in enumerate(self._states)
         ]
+        # the stored ring row may be shorter than the current ring: it
+        # fills the row's first steps
         self._ring = {
-            "addrs": self._ring["addrs"].at[s, :r].set(
-                jax.device_put(rec["ring_addrs"])
-            ),
-            "values": self._ring["values"].at[s, :r].set(
-                jax.device_put(rec["ring_values"])
-            ),
-            "counts": self._ring["counts"].at[s, :r].set(
-                jax.device_put(rec["ring_counts"])
-            ),
+            k: put(v, s, rec[f"ring_{k}"]) for k, v in self._ring.items()
         }
         self._meta = {
-            "done": self._meta["done"].at[s].set(rec["done"]),
-            "total": self._meta["total"].at[s].set(rec["total"]),
-            "admit": self._meta["admit"].at[s].set(0),
-            "fault": self._meta["fault"].at[s].set(0),
+            "done": put(self._meta["done"], s, np.int32(rec["done"])),
+            "total": put(self._meta["total"], s, np.int32(rec["total"])),
+            "admit": put(self._meta["admit"], s, np.int32(0)),
+            "fault": put(self._meta["fault"], s, np.int32(0)),
         }
         self._slot_req[s] = rec["rid"]
         self._slot_parked[s] = rec["parked"]

@@ -27,8 +27,12 @@ TC = 5  # the engine's default chunk_steps
 
 @pytest.fixture(scope="module")
 def topo():
+    import os
+
     from jax.experimental import topologies
 
+    # keep the TPU compiler's logs out of the shared /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
