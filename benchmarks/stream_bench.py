@@ -511,6 +511,7 @@ def open_loop_run(
         params, cfg, num_slots=slots, chunk_steps=Tc, backend="jnp",
         capacities=plan.capacities,
         slos=default_slos(p99_target_s=deadline_s),
+        trace_capacity=8192,
     )
     reqs = [
         StreamRequest(
@@ -1257,7 +1258,7 @@ def _validate_trace_file(path: Path) -> List[str]:
     if not any(e.get("ph") == "M" for e in evs):
         errors.append("trace artifact has no thread metadata")
     names = {e.get("name") for e in spans}
-    for needed in ("chunk", "dispatch", "queue"):
+    for needed in ("occupy", "engine.tick.dispatch", "queue"):
         if needed not in names:
             errors.append(f"trace artifact missing {needed!r} spans")
     bad = [

@@ -37,10 +37,17 @@ the summary prints the fault-plane counters plus ``engine.health()``'s
 diagnosis verdict.
 
 Observability (with --snn): ``--metrics-json`` dumps the engine's full
-instrument snapshot, ``--trace-out`` writes per-request + per-tick-phase
-spans as Perfetto-loadable Chrome trace JSON, ``--timeseries-out`` the
-per-tick time series as JSONL, and ``--profile-ticks N`` wraps N
-steady-state ticks in a programmatic ``jax.profiler`` capture.  Both
+instrument snapshot; ``--trace-out`` turns the engine's span ring on
+(it is off by default) and writes it as Perfetto-loadable Chrome trace
+JSON: the request lifecycle (``submit``, ``queue``, ``engine.admit``,
+``occupy`` on the slot's track, ``complete``) and the serving thread's
+phases (``engine.poll``, ``engine.submit``, ``engine.admit.upload`` /
+``.dispatch``, ``engine.tick.prep``, ``engine.tick.dispatch``,
+``engine.retire`` / ``.fetch``, ``engine.finalize``, ``engine.sample``).
+``--timeseries-out`` writes the per-tick time series as JSONL, and
+``--profile-ticks N`` wraps N steady-state ticks in a programmatic
+``jax.profiler`` capture, which shows the same engine phases as host
+annotations against the device's operations.  Both
 open- and closed-loop modes report the trailing-window miss-rate /
 events/s / ticks/s and the SLO burn-rate verdict
 (healthy/degraded/breach) from ``engine.health()``.
@@ -149,6 +156,7 @@ def _serve_snn(args) -> None:
         slos=default_slos(p99_target_s=deadline_s or 1.0),
         admission=admission, injector=injector,
         preempt=args.preempt,
+        trace_capacity=8192 if args.trace_out else 0,
     )
 
     # crash safety: warm-restart from the latest intact snapshot under
@@ -460,8 +468,9 @@ def main(argv=None):
                     help="write the engine's metrics-registry snapshot "
                          "(counters/gauges/histograms) to this path")
     ap.add_argument("--trace-out", default=None,
-                    help="write per-request + per-tick-phase spans as "
-                         "Chrome trace-event JSON (Perfetto-loadable)")
+                    help="record the engine's span ring (off otherwise) "
+                         "and write it as Chrome trace-event JSON "
+                         "(Perfetto-loadable)")
     ap.add_argument("--timeseries-out", default=None,
                     help="write the per-tick time series (counter "
                          "deltas, windowed rates) as JSONL")

@@ -18,11 +18,11 @@ Two instruments for ROADMAP item 2's open question — *where do the
   share is the true host cost either way.
 
 ``tick_instrumentation_cost_us(...)`` microbenches the exact
-metrics/trace operations one engine tick performs — including the
-per-tick time-series sample the windowed-rate/SLO layer adds — against
-*scratch* instruments, so ``stream_bench.py`` can assert the
-observability layer costs <2% of a tick without perturbing the live
-registry.
+metrics/trace operations one engine tick performs — its phase
+annotations, histograms, counters and the per-tick time-series sample
+the windowed-rate/SLO layer adds — against *scratch* instruments, so
+``stream_bench.py`` can assert the observability layer costs <2% of a
+tick without perturbing the live registry.
 """
 
 from __future__ import annotations
@@ -166,17 +166,21 @@ def tick_instrumentation_cost_us(
     num_slots: int, reps: int = 2000
 ) -> float:
     """Measured cost (µs) of the metrics/trace work one engine tick
-    performs, against scratch instruments: 3 tick-phase histogram
-    records + 3 tick-phase spans, one chunk span per slot, the
-    counter/gauge updates ``_tick``/``_retire`` make, and one
-    time-series sample (with latency-bucket tracking) as taken by the
-    windowed-rate/SLO layer each ``poll()``.  This is the number
-    ``stream_bench.py`` compares against the measured tick time to
-    bound instrumentation overhead."""
+    performs, against scratch instruments: the phases of a poll that
+    dispatches one chunk and retires one (``engine.poll``,
+    ``engine.tick.prep``, ``engine.tick.dispatch``, ``engine.retire``,
+    ``engine.retire.fetch``, ``engine.sample``) on a recorder with the
+    engine's default ring (off), 3 tick-phase histogram records, the
+    counter/gauge updates ``_tick``/``_retire`` make (events and steps
+    per slot), and one time-series sample (with latency-bucket
+    tracking) as taken by the windowed-rate/SLO layer each ``poll()``.
+    Per-request work (admission, finalize) is left out.  This is the
+    number ``stream_bench.py`` compares against the measured tick time
+    to bound instrumentation overhead."""
     from repro.obs.timeseries import TimeSeriesSampler
 
     reg = MetricsRegistry()
-    rec = TraceRecorder(capacity=1024)
+    rec = TraceRecorder(capacity=0)
     hs = [
         reg.histogram(f"probe.tick.{k}_s", lo=1e-7, hi=10.0)
         for k in ("host_prep", "dispatch", "stats_fetch")
@@ -191,22 +195,26 @@ def tick_instrumentation_cost_us(
         reg, capacity=4096, track_buckets=("probe.request.latency_s",)
     )
     t_start = time.perf_counter()
+    phase = rec.phase
     for i in range(reps):
-        t0 = time.perf_counter()
-        for h in hs:
-            h.record(1.1e-3)
-        rec.span("host_prep", t0, t0 + 1e-5, track="tick")
-        rec.span("dispatch", t0, t0 + 1e-3, track="tick")
-        rec.span("stats_fetch", t0, t0 + 1e-4, track="tick")
-        for s in range(num_slots):
-            rec.span(
-                "chunk", t0, t0 + 1e-3,
-                track=f"slot{s}", args={"rid": i, "steps": 5},
-            )
-        ticks.inc()
-        events.inc(1234.0)
-        steps.inc(20.0)
-        depth.set(float(i % 7))
-        lat.record(0.01 * (1 + i % 3))
-        sampler.sample()
+        with phase("engine.poll", track="engine"):
+            with phase("engine.tick.prep", track="tick") as prep:
+                pass
+            with phase("engine.tick.dispatch", track="tick") as disp:
+                pass
+            t2 = time.perf_counter()
+            with phase("engine.retire", track="tick"):
+                with phase("engine.retire.fetch", track="tick"):
+                    pass
+                for _ in range(num_slots):
+                    events.inc(1234.0)
+                    steps.inc(5.0)
+            hs[0].record(prep.duration_s)
+            hs[1].record(disp.duration_s)
+            hs[2].record(time.perf_counter() - t2)
+            ticks.inc()
+            depth.set(float(i % 7))
+            lat.record(0.01 * (1 + i % 3))
+            with phase("engine.sample", track="engine"):
+                sampler.sample()
     return (time.perf_counter() - t_start) / reps * 1e6

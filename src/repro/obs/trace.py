@@ -1,22 +1,26 @@
-"""Per-request span tracing for the SNN stream engine.
+"""Span tracing for the SNN stream engine: one span API, two sinks.
 
-A ``TraceRecorder`` holds a bounded ring (``collections.deque`` with
-``maxlen``) of *completed* spans — recording never allocates unbounded
-memory in an always-on engine; the oldest spans fall off the back.  The
-engine records two families of spans:
+``TraceRecorder.phase(name, track=...)`` times a block of host work.  It
+enters a ``jax.profiler.TraceAnnotation`` of the same name, so under a
+``jax.profiler`` capture the block lands on the profiler's host plane, on
+the device trace's clock, beside the device's operations (about half a
+microsecond when no capture is active); on exit it appends the block as a
+``Span`` to the recorder's ring, if the ring is enabled, and gives the
+caller its ``t0``/``t1`` so histograms keep their exact sums from the same
+timestamps.  The engine's phases (``engine.poll``, ``engine.submit``,
+``engine.admit`` with ``.upload`` and ``.dispatch``, ``engine.tick.prep``,
+``engine.tick.dispatch``, ``engine.retire`` with ``.fetch``,
+``engine.finalize``, ``engine.sample``) nest as the calls nest.
 
-- **Request lifecycle** — ``submit`` (instant, on the queue track),
-  ``queue`` (submit -> admission), ``stage`` (the admission upload +
-  on-device encode, on the winning slot's track), one ``chunk`` span per
-  tick that advanced the request (slot track, tagged with the request id
-  and steps taken), and ``complete`` (instant, with latency / energy /
-  deadline verdict args).
-- **Tick phases** — ``host_prep`` / ``dispatch`` / ``stats_fetch`` spans
-  on a dedicated ``tick`` track, one triple per engine tick, so queue
-  stalls and pipeline bubbles are visible as gaps on a timeline.
+The ring is a bounded ``collections.deque`` of *completed* spans; the
+oldest fall off the back, and capacity 0 turns it off.  ``span`` and
+``instant`` record into the ring alone: the request lifecycle's
+``submit`` (instant, queue track), ``queue`` (submit -> admission, which
+crosses polls), ``occupy`` (admission end -> completion, one per request
+on its slot's track), ``complete``, ``shed``, ``park`` and ``quarantine``.
 
-Timestamps are ``time.perf_counter()`` seconds; export shifts them to a
-common zero.  ``chrome_trace()`` emits Chrome trace-event JSON (the
+Ring timestamps are ``time.perf_counter()`` seconds; export shifts them to
+a common zero.  ``chrome_trace()`` emits Chrome trace-event JSON (the
 ``traceEvents`` array format) loadable in Perfetto (ui.perfetto.dev) or
 ``chrome://tracing``: each distinct track becomes a named thread of one
 ``engine`` process, spans are ``ph: "X"`` complete events, instants are
@@ -31,7 +35,9 @@ import json
 import time
 from typing import Dict, List, Optional
 
-__all__ = ["Span", "TraceRecorder"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Phase", "Span", "TraceRecorder"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,14 +56,48 @@ class Span:
         return 0.0 if self.t1 is None else self.t1 - self.t0
 
 
+class Phase:
+    """A timed block of host work; see ``TraceRecorder.phase``.  After
+    the block, ``t0``/``t1`` are its ``perf_counter`` bounds."""
+
+    __slots__ = ("_rec", "_ann", "name", "track", "args", "t0", "t1")
+
+    def __init__(self, rec: "TraceRecorder", name: str, track: str,
+                 args: Optional[Dict]):
+        self._rec = rec
+        self.name = name
+        self.track = track
+        self.args = args
+
+    def __enter__(self) -> "Phase":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._rec.enabled:
+            self._rec._spans.append(
+                Span(self.name, self.t0, self.t1, self.track, args=self.args)
+            )
+
+    @property
+    def duration_s(self) -> float:
+        return self.t1 - self.t0
+
+
 class TraceRecorder:
-    """Bounded ring of completed spans + Chrome trace-event export."""
+    """Bounded ring of completed spans + Chrome trace-event export.
+    ``capacity=0`` keeps no ring: ``phase`` still annotates the
+    profiler's timeline and times its block."""
 
     def __init__(self, capacity: int = 8192, enabled: bool = True):
-        if capacity < 1:
-            raise ValueError("trace capacity must be >= 1")
+        if capacity < 0:
+            raise ValueError("trace capacity must be >= 0")
         self.capacity = int(capacity)
-        self.enabled = bool(enabled)
+        self.enabled = bool(enabled) and self.capacity > 0
         self._spans: "collections.deque[Span]" = collections.deque(
             maxlen=self.capacity
         )
@@ -65,6 +105,13 @@ class TraceRecorder:
     @staticmethod
     def now() -> float:
         return time.perf_counter()
+
+    def phase(
+        self, name: str, *, track: str, args: Optional[Dict] = None
+    ) -> Phase:
+        """``with recorder.phase(name, track=...) as p:`` times the block
+        as a profiler annotation and, if the ring is on, a span."""
+        return Phase(self, name, track, args)
 
     def span(
         self,

@@ -63,13 +63,19 @@ avoidance — is a latency-critical, always-on workload, so the engine is an
   ad-hoc scalar accumulators: per-request latency / queue-wait / energy
   histograms, episode-scoped counters (events, steps, completions,
   deadline misses — reset when an episode opens, so nothing goes stale
-  across episodes), per-tick phase histograms, and a span per request
-  lifecycle stage (submit -> queue -> stage -> per-chunk ticks ->
-  complete) plus per-tick host_prep / dispatch / stats_fetch phase spans.
-  ``metrics_snapshot()`` exports JSON-able instrument state;
-  ``export_trace(path)`` writes a Perfetto-loadable Chrome trace.  The
-  recording cost is host-side only (the jitted chunk is untouched) and
-  ``benchmarks/stream_bench.py`` pins it under 2% of a tick.
+  across episodes) and per-tick phase histograms.  Every place the
+  serving thread spends time is a phase (``engine.poll``,
+  ``engine.submit``, ``engine.admit`` with ``.upload`` and ``.dispatch``,
+  ``engine.tick.prep``, ``engine.tick.dispatch``, ``engine.retire`` with
+  ``.fetch``, ``engine.finalize``, ``engine.sample``): a
+  ``jax.profiler.TraceAnnotation``, so a profiler capture shows each
+  against the device's operations, and, with ``trace_capacity > 0``, a
+  span in the ring beside the request lifecycle (submit -> queue ->
+  engine.admit -> occupy -> complete).  ``metrics_snapshot()`` exports
+  JSON-able instrument state; ``export_trace(path)`` writes a
+  Perfetto-loadable Chrome trace.  The recording cost is host-side only
+  (the jitted chunk is untouched) and ``benchmarks/stream_bench.py``
+  pins it under 2% of a tick.
 - **Time series + SLOs.** A ``TimeSeriesSampler`` (``engine.timeseries``)
   captures a registry delta on every tick and admission, turning the
   lifetime counters into *windowed* rates — events/s, ticks/s,
@@ -352,7 +358,7 @@ class SNNStreamEngine:
         capacities: Optional[Sequence[int]] = None,
         mesh=None,
         pipeline_depth: int = 1,
-        trace_capacity: int = 8192,
+        trace_capacity: int = 0,
         timeseries_capacity: int = 4096,
         slos: Optional[Sequence] = None,
         admission: Optional[shed_mod.AdmissionPolicy] = None,
@@ -732,8 +738,8 @@ class SNNStreamEngine:
     def _make_instruments(
         self, trace_capacity: int, timeseries_capacity: int
     ) -> None:
-        """Create the engine's metrics registry, span recorder, and
-        windowed time-series sampler.
+        """Create the engine's metrics registry, span recorder (its ring
+        off at ``trace_capacity=0``), and windowed time-series sampler.
 
         Episode-scoped counters live under ``engine.episode.`` and reset
         when an episode opens (first submit on an idle engine); request
@@ -1044,85 +1050,90 @@ class SNNStreamEngine:
         (priority desc, earliest deadline, FIFO) order, so a later submit
         with a tighter deadline overtakes queued work it never saw.
         """
-        T = self._resolve_steps(req)
-        K = self.cfg.layer_sizes[0]
-        if req.spikes is not None:
-            shape = tuple(np.shape(req.spikes))
-            if shape != (T, K):
-                raise ValueError(
-                    f"request spikes shape {shape} != ({T}, {K})"
+        with self.trace.phase("engine.submit", track="queue"):
+            T = self._resolve_steps(req)
+            K = self.cfg.layer_sizes[0]
+            if req.spikes is not None:
+                shape = tuple(np.shape(req.spikes))
+                if shape != (T, K):
+                    raise ValueError(
+                        f"request spikes shape {shape} != ({T}, {K})"
+                    )
+                # staged device-side as int8 event values: trains must be
+                # integer-valued spike magnitudes (all our encoders are)
+                s = np.asarray(req.spikes)
+                if not np.all(np.isfinite(s)):
+                    raise ValueError(
+                        "request spikes contain NaN/inf — non-finite trains "
+                        "are rejected at the admission boundary"
+                    )
+                if not np.all((s == np.round(s)) & (np.abs(s) <= 127)):
+                    raise ValueError(
+                        "request spikes must be integer-valued magnitudes in "
+                        "[-127, 127] (e.g. {0,1} rate codes, {-1,0,1} DVS "
+                        "polarities) — the train is staged as an int8 AER "
+                        "event table"
+                    )
+            elif req.image is not None:
+                shape = tuple(np.shape(req.image))
+                if shape != (K,):
+                    raise ValueError(f"request image shape {shape} != ({K},)")
+                # contents matter, not just shape: a NaN pixel makes
+                # rate_encode (uniform < NaN is always False) emit an
+                # all-zero train — a silently wrong answer, not a crash —
+                # so non-finite images are rejected here at the boundary
+                # (tests/test_faults.py pins the silent-garbage failure)
+                img = np.asarray(req.image)
+                if not np.all(np.isfinite(img)):
+                    raise ValueError(
+                        "request image contains NaN/inf — non-finite images "
+                        "are rejected at the admission boundary"
+                    )
+            else:
+                raise ValueError("StreamRequest needs image or spikes")
+            now = time.perf_counter()
+            if not self._episode_open:
+                self._begin_episode(now)
+            rid = self._next_rid
+            self._next_rid += 1
+            dl = now + req.deadline_s if req.deadline_s is not None else None
+            self._m_submitted.inc()
+            if self.admission is not None:
+                verdict, reason = shed_mod.backpressure(
+                    self.admission,
+                    queue_depth=len(self._queue),
+                    parked_depth=len(self._parked),
+                    priority=req.priority,
                 )
-            # staged device-side as int8 event values: trains must be
-            # integer-valued spike magnitudes (all our encoders are)
-            s = np.asarray(req.spikes)
-            if not np.all(np.isfinite(s)):
-                raise ValueError(
-                    "request spikes contain NaN/inf — non-finite trains "
-                    "are rejected at the admission boundary"
-                )
-            if not np.all((s == np.round(s)) & (np.abs(s) <= 127)):
-                raise ValueError(
-                    "request spikes must be integer-valued magnitudes in "
-                    "[-127, 127] (e.g. {0,1} rate codes, {-1,0,1} DVS "
-                    "polarities) — the train is staged as an int8 AER "
-                    "event table"
-                )
-        elif req.image is not None:
-            shape = tuple(np.shape(req.image))
-            if shape != (K,):
-                raise ValueError(f"request image shape {shape} != ({K},)")
-            # contents matter, not just shape: a NaN pixel makes
-            # rate_encode (uniform < NaN is always False) emit an
-            # all-zero train — a silently wrong answer, not a crash —
-            # so non-finite images are rejected here at the boundary
-            # (tests/test_faults.py pins the silent-garbage failure)
-            img = np.asarray(req.image)
-            if not np.all(np.isfinite(img)):
-                raise ValueError(
-                    "request image contains NaN/inf — non-finite images "
-                    "are rejected at the admission boundary"
-                )
-        else:
-            raise ValueError("StreamRequest needs image or spikes")
-        now = time.perf_counter()
-        if not self._episode_open:
-            self._begin_episode(now)
-        rid = self._next_rid
-        self._next_rid += 1
-        dl = now + req.deadline_s if req.deadline_s is not None else None
-        self._m_submitted.inc()
-        if self.admission is not None:
-            verdict, reason = shed_mod.backpressure(
-                self.admission,
-                queue_depth=len(self._queue),
-                parked_depth=len(self._parked),
-                priority=req.priority,
+                if verdict == shed_mod.SHED:
+                    self._shed(rid, req, now, dl, reason)
+                    self._sample()
+                    return rid
+                if verdict == shed_mod.PARK:
+                    self._park(rid, req, now, dl, reason)
+                    self._sample()
+                    return rid
+            key = (
+                -int(req.priority),
+                0 if dl is not None else 1,  # deadline-less requests last
+                dl if dl is not None else 0.0,
+                self._seq,  # FIFO tiebreak; also keeps heap entries orderable
             )
-            if verdict == shed_mod.SHED:
-                self._shed(rid, req, now, dl, reason)
-                self.timeseries.sample()
-                return rid
-            if verdict == shed_mod.PARK:
-                self._park(rid, req, now, dl, reason)
-                self.timeseries.sample()
-                return rid
-        key = (
-            -int(req.priority),
-            0 if dl is not None else 1,  # deadline-less requests last
-            dl if dl is not None else 0.0,
-            self._seq,  # FIFO tiebreak; also keeps heap entries orderable
-        )
-        self._seq += 1
-        heapq.heappush(self._queue, (key, rid, req, now, dl))
-        self._m_qdepth.set(len(self._queue))
-        self.trace.instant(
-            "submit", now, track="queue",
-            args={"rid": rid, "priority": req.priority},
-        )
-        # admission is a state change worth a time-series point (queue
-        # depth, submitted counter) even between ticks
-        self.timeseries.sample()
-        return rid
+            self._seq += 1
+            heapq.heappush(self._queue, (key, rid, req, now, dl))
+            self._m_qdepth.set(len(self._queue))
+            self.trace.instant(
+                "submit", now, track="queue",
+                args={"rid": rid, "priority": req.priority},
+            )
+            # admission is a state change worth a time-series point (queue
+            # depth, submitted counter) even between ticks
+            self._sample()
+            return rid
+
+    def _sample(self) -> None:
+        with self.trace.phase("engine.sample", track="engine"):
+            self.timeseries.sample()
 
     def _admit(
         self,
@@ -1133,44 +1144,46 @@ class SNNStreamEngine:
         abs_deadline: Optional[float],
     ) -> None:
         T = self._resolve_steps(req)
-        if T > self._ring_steps:
-            self._grow_ring(T)
-        t_stage = time.perf_counter()
-        # every admission upload is *explicit* (device_put), so the whole
-        # serving loop — not just steady-state ticks — runs clean under
-        # jax.transfer_guard("disallow")
-        slot = jax.device_put(np.int32(s))
-        if req.spikes is not None:
-            # single explicit upload of the (T, K) train; compression to
-            # the packed event table happens on device
-            train = jax.device_put(np.asarray(req.spikes, np.float32))
-            self._ring, self._meta = self._admit_spikes_fn(
-                self._ring, self._meta, train, slot
+        track = f"slot{s}"
+        with self.trace.phase(
+            "engine.admit", track=track, args={"rid": rid, "steps": T}
+        ) as admit:
+            # time queued: submit -> admission start, on the queue track
+            self.trace.span(
+                "queue", t_submit, admit.t0, track="queue",
+                args={"rid": rid, "priority": req.priority},
             )
-        else:
-            self._rng, k = jax.random.split(self._rng)
-            img = jax.device_put(np.asarray(req.image, np.float32))
-            self._ring, self._meta = self._admit_image_fn(
-                self._ring, self._meta, img, k, slot, T=T
-            )
-        self._slot_req[s] = rid
-        self._slot_done[s] = 0
-        self._slot_retired[s] = 0
-        self._slot_total[s] = T
-        self._slot_submit_t[s] = t_submit
-        self._slot_admit_t[s] = time.perf_counter()
-        # lifecycle spans: time queued (submit -> stage start) on the
-        # queue track, then the staging upload on the winning slot's
-        # track; queue_wait_s keeps its pre-obs meaning (submit ->
-        # admission complete, staging included)
-        self.trace.span(
-            "queue", t_submit, t_stage, track="queue",
-            args={"rid": rid, "priority": req.priority},
-        )
-        self.trace.span(
-            "stage", t_stage, self._slot_admit_t[s], track=f"slot{s}",
-            args={"rid": rid, "steps": T},
-        )
+            if T > self._ring_steps:
+                self._grow_ring(T)
+            # every admission upload is *explicit* (device_put), so the
+            # whole serving loop — not just steady-state ticks — runs
+            # clean under jax.transfer_guard("disallow"); a dense train
+            # is uploaded once as (T, K) and compressed to the packed
+            # event table on device
+            with self.trace.phase("engine.admit.upload", track=track):
+                slot = jax.device_put(np.int32(s))
+                if req.spikes is not None:
+                    x = jax.device_put(np.asarray(req.spikes, np.float32))
+                else:
+                    self._rng, k = jax.random.split(self._rng)
+                    x = jax.device_put(np.asarray(req.image, np.float32))
+            with self.trace.phase("engine.admit.dispatch", track=track):
+                if req.spikes is not None:
+                    self._ring, self._meta = self._admit_spikes_fn(
+                        self._ring, self._meta, x, slot
+                    )
+                else:
+                    self._ring, self._meta = self._admit_image_fn(
+                        self._ring, self._meta, x, k, slot, T=T
+                    )
+            self._slot_req[s] = rid
+            self._slot_done[s] = 0
+            self._slot_retired[s] = 0
+            self._slot_total[s] = T
+            self._slot_submit_t[s] = t_submit
+            # queue_wait_s keeps its pre-obs meaning: submit ->
+            # admission complete, staging included
+            self._slot_admit_t[s] = time.perf_counter()
         self._m_qwait.record(self._slot_admit_t[s] - t_submit)
         self._slot_deadline[s] = abs_deadline
         self._slot_rel_deadline[s] = req.deadline_s
@@ -1299,23 +1312,27 @@ class SNNStreamEngine:
                 # injected stall: the tick makes no progress at all —
                 # exactly the wedge drain(timeout_s=...) must survive
                 return []
-        t0 = time.perf_counter()
-        take = np.zeros(S, np.int32)
-        for s in range(S):
-            if self._slot_req[s] is None:
-                continue
-            take[s] = min(
-                Tc, int(self._slot_total[s]) - int(self._slot_done[s])
-            )
-        dispatched = bool(take.sum() > 0)
-        t1 = time.perf_counter()
+        with self.trace.phase("engine.tick.prep", track="tick") as prep:
+            take = np.zeros(S, np.int32)
+            for s in range(S):
+                if self._slot_req[s] is None:
+                    continue
+                take[s] = min(
+                    Tc, int(self._slot_total[s]) - int(self._slot_done[s])
+                )
+            dispatched = bool(take.sum() > 0)
+        dispatch_s = 0.0
         if dispatched:
-            self._states, self._meta, stats_dev = self._dispatch_chunk()
-            self._slot_done += take
-            self._inflight.append(
-                (stats_dev, take.copy(), list(self._slot_req))
-            )
-            self._note_chunk_compiles()
+            with self.trace.phase(
+                "engine.tick.dispatch", track="tick"
+            ) as disp:
+                self._states, self._meta, stats_dev = self._dispatch_chunk()
+                self._slot_done += take
+                self._inflight.append(
+                    (stats_dev, take.copy(), list(self._slot_req))
+                )
+                self._note_chunk_compiles()
+            dispatch_s = disp.duration_s
         t2 = time.perf_counter()
         finished: List[int] = []
         # keep at most pipeline_depth chunks' stats in flight; when
@@ -1338,29 +1355,12 @@ class SNNStreamEngine:
             force = 0
             finished.extend(self._retire())
         t3 = time.perf_counter()
-        # tick-phase instruments: histograms keep exact sum/count (the
-        # tick_breakdown means) plus tail percentiles; spans make queue
-        # stalls and pipeline bubbles visible on the trace timeline
-        self._m_prep.record(t1 - t0)
-        self._m_dispatch.record(t2 - t1)
+        # tick-phase histograms keep exact sum/count (the tick_breakdown
+        # means) plus tail percentiles, from the phases' own timestamps
+        self._m_prep.record(prep.duration_s)
+        self._m_dispatch.record(dispatch_s)
         self._m_fetch.record(t3 - t2)
         self._m_active.set(sum(r is not None for r in self._slot_req))
-        self.trace.span("host_prep", t0, t1, track="tick")
-        if dispatched:
-            self.trace.span(
-                "dispatch", t1, t2, track="tick",
-                args={"steps": int(take.sum())},
-            )
-            for s in range(S):
-                if take[s] > 0:
-                    self.trace.span(
-                        "chunk", t1, t2, track=f"slot{s}",
-                        args={
-                            "rid": self._slot_req[s],
-                            "steps": int(take[s]),
-                        },
-                    )
-        self.trace.span("stats_fetch", t2, t3, track="tick")
         return finished
 
     def _note_chunk_compiles(self) -> None:
@@ -1417,31 +1417,33 @@ class SNNStreamEngine:
     def _retire(self) -> List[int]:
         """Fetch the oldest in-flight chunk's stats (the tick's single
         D2H transfer) and fold them into per-slot accumulators."""
-        stats_dev, take, rids = self._inflight.popleft()
-        stats = jax.device_get(stats_dev)
-        fault = stats.get("fault")
-        finished = []
-        for s in range(self.S):
-            if rids[s] is None or take[s] == 0:
-                continue
-            if self._slot_req[s] != rids[s]:
-                continue  # slot was freed and re-admitted since dispatch
-            if fault is not None and int(fault[s]) != 0:
-                # poisoned slot: discard this chunk's stats (they may be
-                # NaN), fail the request into a quarantined result, and
-                # free the slot — the other S-1 slots fold normally and
-                # the in-graph sanitization already cleaned the state
-                self._quarantine(s, int(fault[s]))
-                continue
-            self._slot_counts[s] += stats["counts"][s]
-            self._slot_memsum[s] += stats["memsum"][s]
-            self._slot_events[s] += stats["events"][s]
-            self._slot_retired[s] += int(take[s])
-            self._m_events.inc(float(stats["events"][s].sum()))
-            self._m_steps.inc(int(take[s]))
-            if self._slot_retired[s] >= self._slot_total[s]:
-                finished.append(s)
-        return finished
+        with self.trace.phase("engine.retire", track="tick"):
+            stats_dev, take, rids = self._inflight.popleft()
+            with self.trace.phase("engine.retire.fetch", track="tick"):
+                stats = jax.device_get(stats_dev)
+            fault = stats.get("fault")
+            finished = []
+            for s in range(self.S):
+                if rids[s] is None or take[s] == 0:
+                    continue
+                if self._slot_req[s] != rids[s]:
+                    continue  # slot was freed and re-admitted since dispatch
+                if fault is not None and int(fault[s]) != 0:
+                    # poisoned slot: discard this chunk's stats (they may be
+                    # NaN), fail the request into a quarantined result, and
+                    # free the slot — the other S-1 slots fold normally and
+                    # the in-graph sanitization already cleaned the state
+                    self._quarantine(s, int(fault[s]))
+                    continue
+                self._slot_counts[s] += stats["counts"][s]
+                self._slot_memsum[s] += stats["memsum"][s]
+                self._slot_events[s] += stats["events"][s]
+                self._slot_retired[s] += int(take[s])
+                self._m_events.inc(float(stats["events"][s].sum()))
+                self._m_steps.inc(int(take[s]))
+                if self._slot_retired[s] >= self._slot_total[s]:
+                    finished.append(s)
+            return finished
 
     def _quarantine(self, s: int, code: int) -> None:
         """Fail slot ``s``'s request into a quarantined result and free
@@ -1487,51 +1489,58 @@ class SNNStreamEngine:
         self._slot_parked[s] = False
 
     def _finalize(self, s: int) -> StreamResult:
-        cfg = self.cfg
-        T = int(self._slot_total[s])
-        ev = self._slot_events[s].copy()
-        oc = energy.snn_ops_from_events(
-            cfg.layer_sizes, T, ev, neuron_kind=cfg.neuron_kind
-        )
-        counts = self._slot_counts[s]
-        pred = int(np.argmax(counts + 1e-6 * self._slot_memsum[s]))
-        finish_t = time.perf_counter()
-        dl = self._slot_deadline[s]
-        missed = dl is not None and finish_t > dl
-        self._m_completed.inc()
-        self._m_finished.inc()
-        if missed:
-            self._m_misses.inc()
-            self._m_missed_total.inc()
-        latency_s = finish_t - self._slot_submit_t[s]
-        self._m_latency.record(latency_s)
-        self._m_energy.record(oc.energy_pj())
-        self.trace.instant(
-            "complete", finish_t, track=f"slot{s}",
-            args={
-                "rid": self._slot_req[s],
-                "latency_ms": latency_s * 1e3,
-                "energy_pj": oc.energy_pj(),
-                "deadline_missed": bool(missed),
-            },
-        )
-        res = StreamResult(
-            request_id=self._slot_req[s],
-            prediction=pred,
-            spike_counts=counts.copy(),
-            steps=T,
-            latency_s=latency_s,
-            queue_wait_s=self._slot_admit_t[s] - self._slot_submit_t[s],
-            events_per_layer=ev,
-            spike_rate=float(ev[0] / (T * cfg.layer_sizes[0])),
-            energy_pj=oc.energy_pj(),
-            deadline_s=self._slot_rel_deadline[s],
-            deadline_missed=missed,
-            parked=self._slot_parked[s],
-        )
-        self._slot_req[s] = None
-        self._slot_parked[s] = False
-        return res
+        with self.trace.phase("engine.finalize", track=f"slot{s}"):
+            cfg = self.cfg
+            T = int(self._slot_total[s])
+            ev = self._slot_events[s].copy()
+            oc = energy.snn_ops_from_events(
+                cfg.layer_sizes, T, ev, neuron_kind=cfg.neuron_kind
+            )
+            counts = self._slot_counts[s]
+            pred = int(np.argmax(counts + 1e-6 * self._slot_memsum[s]))
+            finish_t = time.perf_counter()
+            dl = self._slot_deadline[s]
+            missed = dl is not None and finish_t > dl
+            self._m_completed.inc()
+            self._m_finished.inc()
+            if missed:
+                self._m_misses.inc()
+                self._m_missed_total.inc()
+            latency_s = finish_t - self._slot_submit_t[s]
+            self._m_latency.record(latency_s)
+            self._m_energy.record(oc.energy_pj())
+            # the slot's occupancy, admission end -> completion
+            self.trace.span(
+                "occupy", float(self._slot_admit_t[s]), finish_t,
+                track=f"slot{s}",
+                args={"rid": self._slot_req[s], "steps": T},
+            )
+            self.trace.instant(
+                "complete", finish_t, track=f"slot{s}",
+                args={
+                    "rid": self._slot_req[s],
+                    "latency_ms": latency_s * 1e3,
+                    "energy_pj": oc.energy_pj(),
+                    "deadline_missed": bool(missed),
+                },
+            )
+            res = StreamResult(
+                request_id=self._slot_req[s],
+                prediction=pred,
+                spike_counts=counts.copy(),
+                steps=T,
+                latency_s=latency_s,
+                queue_wait_s=self._slot_admit_t[s] - self._slot_submit_t[s],
+                events_per_layer=ev,
+                spike_rate=float(ev[0] / (T * cfg.layer_sizes[0])),
+                energy_pj=oc.energy_pj(),
+                deadline_s=self._slot_rel_deadline[s],
+                deadline_missed=missed,
+                parked=self._slot_parked[s],
+            )
+            self._slot_req[s] = None
+            self._slot_parked[s] = False
+            return res
 
     # -------------------------------------------------------- preemption
     def _drain_inflight(self) -> None:
@@ -2135,36 +2144,37 @@ class SNNStreamEngine:
         return the requests that finished — including shed and
         quarantined dispositions.  Non-blocking in the scheduling sense:
         returns [] when the engine is idle."""
-        self._maybe_preempt()
-        for s in range(self.S):
-            if self._slot_req[s] is None and (
-                self._queue or self._parked or self._preempt_parked
+        with self.trace.phase("engine.poll", track="engine"):
+            self._maybe_preempt()
+            for s in range(self.S):
+                if self._slot_req[s] is None and (
+                    self._queue or self._parked or self._preempt_parked
+                ):
+                    self._fill_slot(s)
+            self._m_qdepth.set(len(self._queue))
+            if (
+                all(r is None for r in self._slot_req)
+                and not self._inflight
             ):
-                self._fill_slot(s)
-        self._m_qdepth.set(len(self._queue))
-        if (
-            all(r is None for r in self._slot_req)
-            and not self._inflight
-        ):
-            results, self._pending_results = self._pending_results, []
-            if results and self.idle() and self._episode_open:
+                results, self._pending_results = self._pending_results, []
+                if results and self.idle() and self._episode_open:
+                    self._m_wall.set(time.perf_counter() - self._episode_t0)
+                    self._episode_open = False
+                if results:
+                    self._sample()
+                return results
+            results = [self._finalize(s) for s in self._tick()]
+            if self._pending_results:
+                results = self._pending_results + results
+                self._pending_results = []
+            if self.idle() and self._episode_open:
                 self._m_wall.set(time.perf_counter() - self._episode_t0)
                 self._episode_open = False
-            if results:
-                self.timeseries.sample()
+            # one time-series point per tick, after completions land, so the
+            # sample sees this tick's counters (misses included) — windowed
+            # rates then track the run as it evolves
+            self._sample()
             return results
-        results = [self._finalize(s) for s in self._tick()]
-        if self._pending_results:
-            results = self._pending_results + results
-            self._pending_results = []
-        if self.idle() and self._episode_open:
-            self._m_wall.set(time.perf_counter() - self._episode_t0)
-            self._episode_open = False
-        # one time-series point per tick, after completions land, so the
-        # sample sees this tick's counters (misses included) — windowed
-        # rates then track the run as it evolves
-        self.timeseries.sample()
-        return results
 
     def drain(
         self, timeout_s: Optional[float] = None

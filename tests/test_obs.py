@@ -252,6 +252,31 @@ def test_trace_ring_is_bounded_and_ordered():
     rec.enabled = False
     rec.span("off", 0.0, 1.0)
     assert len(rec) == 8
+    with pytest.raises(ValueError):
+        TraceRecorder(capacity=-1)
+
+
+@pytest.mark.parametrize("capacity", [0, 8])
+def test_phase_times_its_block_and_fills_the_ring_when_on(capacity):
+    """A phase gives its bounds to the caller either way; it lands in
+    the ring under its own name only when the ring is on."""
+    rec = TraceRecorder(capacity=capacity)
+    with rec.phase("engine.poll", track="engine") as outer:
+        with rec.phase("engine.tick.prep", track="tick",
+                       args={"n": 1}) as inner:
+            pass
+    assert outer.t0 <= inner.t0 <= inner.t1 <= outer.t1
+    assert inner.duration_s == inner.t1 - inner.t0 >= 0
+    assert rec.enabled == (capacity > 0)
+    spans = rec.spans()
+    if capacity:
+        # completed in exit order: the inner block first
+        assert [(s.name, s.track) for s in spans] == [
+            ("engine.tick.prep", "tick"), ("engine.poll", "engine")]
+        assert (spans[0].t0, spans[0].t1) == (inner.t0, inner.t1)
+        assert spans[0].args == {"n": 1}
+    else:
+        assert spans == []
 
 
 def test_chrome_trace_round_trip(tmp_path):
@@ -285,10 +310,12 @@ def test_chrome_trace_round_trip(tmp_path):
 # ------------------------------------------- engine lifecycle invariants
 def test_engine_span_lifecycle_invariants():
     """Every completed request leaves a full span lifecycle in the ring:
-    queue -> stage -> >=1 chunk -> complete, with monotonic timestamps
-    all ordered within the request."""
+    queue -> engine.admit -> occupy -> complete, with monotonic
+    timestamps all ordered within the request."""
     params = _params()
-    eng = SNNStreamEngine(params, CFG, num_slots=2, chunk_steps=6)
+    eng = SNNStreamEngine(
+        params, CFG, num_slots=2, chunk_steps=6, trace_capacity=8192
+    )
     n_req = 5
     rids = [
         eng.submit(StreamRequest(spikes=_train(0.3, s)))
@@ -306,25 +333,42 @@ def test_engine_span_lifecycle_invariants():
         kinds = [s.name for s in mine]
         assert "submit" in kinds
         assert "queue" in kinds
-        assert "stage" in kinds
+        assert "engine.admit" in kinds
         assert "complete" in kinds
-        assert kinds.count("chunk") >= 1
+        assert kinds.count("occupy") == 1
         by = {s.name: s for s in mine}
-        queue, stage = by["queue"], by["stage"]
-        chunks = [s for s in mine if s.name == "chunk"]
+        queue, stage = by["queue"], by["engine.admit"]
+        occupy = by["occupy"]
         complete = by["complete"]
         # lifecycle ordering: submit == queue start <= queue end ==
-        # stage start <= every chunk <= complete
-        assert queue.t0 <= queue.t1 <= stage.t0 <= stage.t1
-        for c in chunks:
-            assert stage.t1 <= c.t1 <= complete.t0
+        # admission start <= occupancy start (staging done) <=
+        # admission end <= occupancy end == complete
+        assert queue.t0 <= queue.t1 == stage.t0 <= stage.t1
+        assert stage.t0 <= occupy.t0 <= stage.t1 <= occupy.t1
+        assert occupy.t1 == complete.t0
+        assert occupy.track == stage.track == complete.track
         assert queue.t0 == by["submit"].t0
         # completion args carry the result-facing fields
         assert complete.args["latency_ms"] > 0
         assert complete.args["energy_pj"] > 0
     # tick-phase spans exist on their own track
-    assert any(s.track == "tick" and s.name == "dispatch" for s in spans)
-    assert any(s.track == "tick" and s.name == "host_prep" for s in spans)
+    assert any(
+        s.track == "tick" and s.name == "engine.tick.dispatch" for s in spans
+    )
+    assert any(
+        s.track == "tick" and s.name == "engine.tick.prep" for s in spans
+    )
+
+
+def test_default_engine_keeps_no_ring():
+    """The span ring is off unless asked for; the tick histograms are
+    fed from the phases all the same."""
+    eng = SNNStreamEngine(_params(), CFG, num_slots=2, chunk_steps=6)
+    eng.run([StreamRequest(spikes=_train(0.3, s)) for s in range(3)])
+    assert not eng.trace.enabled and eng.trace.spans() == []
+    tb = eng.tick_breakdown()
+    assert tb["ticks"] > 0 and tb["host_prep_us"] > 0
+    assert tb["dispatch_us"] > 0 and tb["stats_fetch_us"] > 0
 
 
 def test_engine_metrics_snapshot_consistency():
