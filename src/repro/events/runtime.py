@@ -23,6 +23,7 @@ serving engine can carry membrane potentials across request chunks.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -46,22 +47,37 @@ def step_events(x: Array, capacity: int) -> Tuple[Array, Array, Array]:
 
     Returns (addrs (..., C) int32, values (..., C) float32, count (...,)
     int32); ``values`` carries the (signed) spike magnitude, 0 on padding.
+    Addresses are the active positions in ascending order; at
+    ``capacity`` the list truncates to the *first* ``capacity`` of them,
+    and past ``count`` both lists are 0.
 
-    O(K + C log K) cumsum-based stable compaction (vs the original
-    O(K log K) argsort, kept as ``step_events_argsort`` for oracle and
-    baseline-benchmark use): a running count over the plane assigns each
-    active position its output slot (its cumsum rank), and because that
-    rank sequence is monotone the *inverse* map — which source position
-    feeds output slot c — is a vectorized binary search, i.e. a gather.
-    Expressing the compaction as a gather instead of the literal
-    rank-scatter matters: XLA lowers generic scatters poorly on CPU (and
-    serializes them on TPU), while searchsorted + take_along_axis stay
-    vectorized on both; measured ~15-25x faster than either the scatter
-    or the argsort at the collision config (benchmarks/snn_bench.py).
-    At ``capacity`` the list truncates to the *first* ``capacity`` active
-    positions — identical truncation semantics to the argsort path
-    (property-tested).
+    The compaction is lowered per platform, and both branches return
+    bitwise identical arrays (tests/test_snn_chunk.py):
+
+    - CPU and every other platform but the TPU (``_compact_search``): a
+      cumsum gives each active position its output slot, and the inverse
+      map (which position feeds slot c) is a vectorized binary search
+      plus one gather.  Measured on the CPU ~15-25x faster than the
+      argsort (benchmarks/snn_bench.py) and about 6x faster than the
+      sort below.
+    - TPU (``_compact_sort``): one sort along K of unique keys (the
+      address, offset by K where the position is silent) carrying ``x``
+      as payload, so neither a ``while`` loop nor a gather remains.  On a
+      TPU v5e each gathered element costs about 10 ns: the search's
+      ``while`` took log2 K + 1 times the final values gather (13.0x at
+      K=4096, 11.0x at K=1024), 14.7 ms per (25, 4096) admission in the
+      benchmark's device trace, while the sort is a native vectorized
+      network.
     """
+    return jax.lax.platform_dependent(
+        x,
+        tpu=functools.partial(_compact_sort, capacity=capacity),
+        default=functools.partial(_compact_search, capacity=capacity),
+    )
+
+
+def _compact_search(x: Array, capacity: int) -> Tuple[Array, Array, Array]:
+    """``step_events`` by cumsum ranks and a binary search: O(K + C log K)."""
     K = x.shape[-1]
     lead = x.shape[:-1]
     active = x != 0
@@ -81,11 +97,40 @@ def step_events(x: Array, capacity: int) -> Tuple[Array, Array, Array]:
     return addrs, values.astype(jnp.float32), count
 
 
+def _compact_sort(x: Array, capacity: int) -> Tuple[Array, Array, Array]:
+    """``step_events`` by one payload sort: no loop, no gather."""
+    K = x.shape[-1]
+    axis = x.ndim - 1
+    active = x != 0
+    count = jnp.minimum(
+        jnp.sum(active, axis=-1, dtype=jnp.int32), capacity
+    ).astype(jnp.int32)
+    # unique keys: active positions first, each group in address order,
+    # so the sorted keys' head is the addresses and no stable sort (which
+    # compiles about 3x slower for a TPU) is needed
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    src, vals = jax.lax.sort(
+        (jnp.where(active, iota, iota + K), x),
+        dimension=axis,
+        is_stable=False,
+        num_keys=1,
+    )
+    src, vals = src[..., :capacity], vals[..., :capacity]
+    if capacity > K:
+        pad = [(0, 0)] * axis + [(0, capacity - K)]
+        src, vals = jnp.pad(src, pad), jnp.pad(vals, pad)
+    valid = jnp.arange(capacity, dtype=jnp.int32) < count[..., None]
+    addrs = jnp.where(valid, src, 0)
+    values = jnp.where(valid, vals, 0.0)
+    return addrs, values.astype(jnp.float32), count
+
+
 def step_events_argsort(x: Array, capacity: int) -> Tuple[Array, Array, Array]:
     """Original argsort-compaction event extraction (O(K log K)).
 
-    Kept as the oracle for ``step_events`` and as the PR-2 baseline in
-    ``benchmarks/snn_bench.py``; the O(K) scatter above is the hot path.
+    Kept as the oracle for ``step_events`` and as the baseline in
+    ``benchmarks/snn_bench.py``; ``step_events`` is the path every caller
+    runs.
     """
     active = x != 0
     order = jnp.argsort(~active, axis=-1, stable=True)[..., :capacity]

@@ -244,6 +244,87 @@ def test_step_events_empty_plane():
     assert not np.asarray(count).any()
 
 
+# ------------------------------------- step_events: TPU sort vs CPU search
+_COMPACT = {
+    name: jax.jit(fn, static_argnums=1)
+    for name, fn in [
+        ("sort", runtime._compact_sort),
+        ("search", runtime._compact_search),
+        ("argsort", runtime.step_events_argsort),
+    ]
+}
+
+
+def _plane(kind, shape):
+    rng = np.random.default_rng(shape)
+    if kind == "binary":
+        x = rng.random(shape) < 0.4
+    elif kind == "signed":
+        x = (rng.random(shape) < 0.4) * rng.choice([-1, 1], shape)
+    elif kind == "multi":
+        x = (rng.random(shape) < 0.4) * rng.integers(-3, 4, shape)
+    elif kind == "zero":
+        x = np.zeros(shape)
+    else:  # "full": every position active
+        x = rng.choice([-2, -1, 1, 2], shape)
+    return jnp.asarray(x, jnp.float32)
+
+
+@pytest.mark.parametrize("kind", ["binary", "signed", "multi", "zero", "full"])
+@pytest.mark.parametrize("cap", ["K", "K/2", "7", "K+5"])
+@pytest.mark.parametrize("shape", [(25, 4096), (25, 1024), (3, 2, 37)])
+def test_step_events_sort_branch_matches_search_bitwise(
+    shape, cap, kind, monkeypatch
+):
+    """The TPU branch (one payload sort), run here on the CPU, is
+    bitwise the CPU branch (binary search) and the argsort oracle, also
+    through ``encode_step_table``'s int16/int8 packing."""
+    K = shape[-1]
+    C = {"K": K, "K/2": K // 2, "7": 7, "K+5": K + 5}[cap]
+    x = _plane(kind, shape)
+    got = [np.asarray(a) for a in _COMPACT["sort"](x, C)]
+    # the argsort oracle cannot pad past K; the search branch can
+    for ref in ("search", "argsort") if C <= K else ("search",):
+        want = [np.asarray(a) for a in _COMPACT[ref](x, C)]
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+    if kind == "full":
+        assert (got[2] == min(C, K)).all()
+
+    def encode(x):
+        return runtime.encode_step_table(x, C)
+
+    want = jax.jit(encode)(x)
+    monkeypatch.setattr(runtime, "step_events", runtime._compact_sort)
+    got = jax.jit(encode)(x)
+    assert got.addrs.dtype == want.addrs.dtype == jnp.int16
+    assert got.values.dtype == want.values.dtype == jnp.int8
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_encode_step_table_lowering_per_platform(platform):
+    """Lowered for a TPU, the admission encode at the paper's (25, 4096),
+    C=4096 holds neither a ``while`` loop nor a gather, only the sort; the
+    CPU lowering keeps the binary search and no sort."""
+    x = jax.ShapeDtypeStruct((25, 4096), jnp.float32)
+    text = (
+        jax.jit(lambda x: runtime.encode_step_table(x, 4096))
+        .trace(x)
+        .lower(lowering_platforms=(platform,))
+        .as_text()
+    )
+    if platform == "tpu":
+        assert "stablehlo.sort" in text
+        assert "stablehlo.while" not in text
+        assert "gather" not in text
+    else:
+        assert "stablehlo.while" in text and "gather" in text
+        assert "stablehlo.sort" not in text
+
+
 # --------------------------------------------------------------- autotuner
 def test_autotune_capacity_bounds_and_report():
     cfg = snn.SNNConfig(layer_sizes=(64, 24, 2), num_steps=10)

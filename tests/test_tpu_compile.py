@@ -106,3 +106,24 @@ def test_engine_chunk_compiles_for_v5e(
     )
     compiled = engine._chunk.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_admission_compiles_for_v5e(one_chip, no_compile_cache):
+    """The engine's admission program at its defaults (a (25, 4096)
+    train, C=4096) compiles for a TPU to the payload sort, with neither
+    the binary search's ``while`` loop nor a gather."""
+    engine = SNNStreamEngine(
+        snn.init_params(jax.random.PRNGKey(0), CONFIG), CONFIG
+    )
+    assert engine.C == 4096
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    args = jax.tree.map(spec, (engine._ring, engine._meta)) + (
+        spec(jax.ShapeDtypeStruct((CONFIG.num_steps, 4096), jnp.float32)),
+        spec(jax.ShapeDtypeStruct((), jnp.int32)),
+    )
+    text = engine._admit_spikes_fn.lower(*args).compile().as_text()
+    assert " sort(" in text
+    assert " while(" not in text and " gather(" not in text
