@@ -22,11 +22,17 @@ Per kernel it reports:
 
 Findings use codes RB301 (VMEM over budget), RB302 (index map out of
 bounds), RB303 (block does not divide operand), RB304 (SMEM over
-budget).  Budgets are configurable; defaults are the v4/v5 TPU figures
-from the Pallas guide (16 MiB VMEM/core) with a deliberately tight
-1 MiB line for SMEM.  The estimate covers *declared*
+budget).  A kernel's VMEM budget is the scoped limit it asks Mosaic for
+(``vmem_limit_bytes``), or the compiler's default of 16 MiB where it asks
+none, and no limit may pass the core's VMEM (128 MiB on a v5e); SMEM has
+a deliberately tight 1 MiB line.  The estimate covers *declared*
 buffers only — compiler-managed temporaries (e.g. the (bm, bk, bn)
 int32 product in ``q115_matmul``) are the compiler's to spill.
+
+``snn_chunk`` does not only get checked here: it stages itself by
+``snn_chunk_staging``, which prices its geometry from the shapes and
+derives how many steps of event rows a grid step brings into SMEM and
+the scoped VMEM limit; the captured call then checks that price.
 """
 
 from __future__ import annotations
@@ -41,15 +47,95 @@ from jax.experimental import pallas as pl
 
 from .jaxlint import Finding
 
-DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024  # per-core VMEM (TPU v4/v5 class)
+DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024  # scoped VMEM of a kernel asking none
 DEFAULT_SMEM_BUDGET = 1024 * 1024  # scalar-prefetch tables + SMEM blocks
+VMEM_CAPACITY = 128 * 1024 * 1024  # a TPU v5e core's VMEM: the most to ask
+# beside the declared blocks: their padding to (8, 128) tiles (about 0.5
+# MiB at 16384-512-2) and Mosaic's own scratch
+_VMEM_HEADROOM = 4 * 1024 * 1024
+_LANE = 128  # lane width: the kernels pad widths to it
+_EV_PAD = 128  # snn_chunk's event-count lane
 
-# the (4096, 512, 2) collision config at serving geometry — the paper's
-# headline workload and what stream_bench drives
+# the (4096, 512, 2) collision config — the paper's headline workload
+# and what stream_bench drives; snn_chunk is priced at the serving
+# engine's defaults (8 slots, Tc=5, no capacity plan: C = fan-in) for it
+# and for the paper's 128 px input, 16384-512-2
 _COLLISION_LAYERS = ((4096, 512), (512, 2))
-_SLOTS = 4
+_COLLISION_128PX = (16384, 512, 2)
+_SLOTS = 8
 _CHUNK_STEPS = 5
 _CAPACITY = 13 * 128  # layer-0 event capacity (autotuned ballpark)
+
+
+@dataclasses.dataclass(frozen=True)
+class ChunkStaging:
+    """How ``snn_chunk`` stages one call, priced from its shapes."""
+
+    steps_per_block: int  # steps of one slot's event rows per grid step
+    weight_bytes: int  # layer-0 slab, brought into VMEM once a call
+    event_row_bytes: int  # address and value rows staged into SMEM a call
+    smem_bytes: int
+    vmem_bytes: int  # declared VMEM working set
+    vmem_limit_bytes: int  # the scoped VMEM limit the kernel is given
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def snn_chunk_staging(
+    fan_ins: Sequence[int],
+    outs: Sequence[int],
+    slots: int,
+    chunk_steps: int,
+    capacity: int,
+    block_e: int = 128,
+) -> ChunkStaging:
+    """Price one ``snn_chunk`` call at the geometry it is given (unpadded
+    fan-ins and widths, slots, chunk steps, layer-0 event capacity) and
+    choose its staging; the kernel stages itself by this.
+
+    Event rows come in ``ts`` steps of one slot per grid step, ``ts`` the
+    largest divisor of the chunk whose double-buffered address and value
+    blocks, with the prefetched mask and counts, fit SMEM.  VMEM is
+    counted as ``check_kernel_budgets`` counts a captured call: blocks
+    constant over the grid (the slabs) once, per-slot blocks twice.  The
+    scoped limit is that working set plus headroom for tile padding and
+    Mosaic's own scratch, and never below the compiler's default.  A
+    geometry that cannot fit is priced all the same;
+    ``check_kernel_budgets`` reports it.
+    """
+    cp = _round_up(capacity, min(block_e, capacity))
+    np_out = [_round_up(n, _LANE) for n in outs]
+    rows = [int(fan_ins[0])] + np_out[:-1]  # hidden slabs take padded rows
+    prefetch = 4 * (slots + slots * chunk_steps)  # active mask, counts
+
+    def smem(ts: int) -> int:
+        return prefetch + 2 * 2 * ts * cp * 4  # two tables, two copies
+
+    ts = max((d for d in range(1, chunk_steps + 1)
+              if chunk_steps % d == 0 and smem(d) <= DEFAULT_SMEM_BUDGET),
+             default=1)
+    f32 = 4
+    row = sum(np_out) * f32  # one (1, N) row of every layer
+    vmem = (
+        sum(r * n for r, n in zip(rows, np_out)) * f32  # slabs, resident
+        + 3 * row  # biases, betas, thresholds, resident
+        + 2 * 2 * row  # incoming membranes and refractory, per slot
+        + 2 * 2 * row  # final membranes and refractory, per slot
+        + 2 * (2 * np_out[-1] + _EV_PAD) * chunk_steps * f32  # mem, spk, ev
+        + 2 * row  # state scratch
+    )
+    limit = max(DEFAULT_VMEM_BUDGET,
+                _round_up(vmem + _VMEM_HEADROOM, 1 << 20))
+    return ChunkStaging(
+        steps_per_block=ts,
+        weight_bytes=rows[0] * np_out[0] * f32,
+        event_row_bytes=slots * chunk_steps * cp * 2 * 4,
+        smem_bytes=smem(ts),
+        vmem_bytes=vmem,
+        vmem_limit_bytes=limit,
+    )
 
 
 @dataclasses.dataclass
@@ -80,6 +166,7 @@ class KernelPlan:
     buffers: list[BufferPlan]
     smem_bytes: int
     errors: list[str]
+    vmem_limit_bytes: int | None = None  # the scoped limit it asks for
 
     @property
     def vmem_bytes(self) -> int:
@@ -92,6 +179,7 @@ class KernelPlan:
             "num_scalar_prefetch": self.num_scalar_prefetch,
             "vmem_bytes": self.vmem_bytes,
             "smem_bytes": self.smem_bytes,
+            "vmem_limit_bytes": self.vmem_limit_bytes,
             "buffers": [b.to_json() for b in self.buffers],
             "errors": self.errors,
         }
@@ -276,7 +364,9 @@ def _analyze_record(name: str, rec: dict) -> KernelPlan:
                            np.dtype(jnp.dtype(dtype)).name, nbytes, 1, True)
             )
 
-    return KernelPlan(name, grid, npf, buffers, smem + sum(smem_blocks), errors)
+    limit = getattr(kw.get("compiler_params"), "vmem_limit_bytes", None)
+    return KernelPlan(name, grid, npf, buffers, smem + sum(smem_blocks),
+                      errors, limit)
 
 
 # ---------------------------------------------------------------------------
@@ -284,27 +374,42 @@ def _analyze_record(name: str, rec: dict) -> KernelPlan:
 # ---------------------------------------------------------------------------
 
 
-def _plan_snn_chunk() -> KernelPlan:
+def plan_snn_chunk(
+    layer_sizes: Sequence[int] = (4096, 512, 2),
+    slots: int = _SLOTS,
+    chunk_steps: int = _CHUNK_STEPS,
+    capacity: int | None = None,
+) -> KernelPlan:
+    """Capture ``snn_chunk`` at a geometry (``capacity`` None: the fan-in,
+    as the engine stages without a capacity plan) on abstract shapes, so
+    a slab of any size costs no memory."""
+    import jax
+
     from repro.kernels import snn_chunk as mod
 
-    L = len(_COLLISION_LAYERS)
-    B, Tc, C = _SLOTS, _CHUNK_STEPS, _CAPACITY
-    weights = [np.zeros(s, np.float32) for s in _COLLISION_LAYERS]
-    biases = [np.zeros(s[1], np.float32) for s in _COLLISION_LAYERS]
-    betas = [np.full(s[1], 0.9, np.float32) for s in _COLLISION_LAYERS]
-    thresholds = [np.ones(s[1], np.float32) for s in _COLLISION_LAYERS]
-    u0 = [np.zeros((B, s[1]), np.float32) for s in _COLLISION_LAYERS]
-    r0 = [np.zeros((B, s[1]), np.int32) for s in _COLLISION_LAYERS]
-    addrs = np.zeros((Tc, B, C), np.int16)
-    values = np.zeros((Tc, B, C), np.int8)
-    counts = np.zeros((Tc, B), np.int32)
-    active = np.ones((B,), np.int32)
+    B, Tc = slots, chunk_steps
+    C = layer_sizes[0] if capacity is None else capacity
+    n = layer_sizes[1:]
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    args = (
+        [f32(k, m) for k, m in zip(layer_sizes[:-1], n)],
+        [f32(m) for m in n], [f32(m) for m in n], [f32(m) for m in n],
+        [f32(B, m) for m in n],
+        [jax.ShapeDtypeStruct((B, m), jnp.int32) for m in n],
+        jax.ShapeDtypeStruct((B, Tc, C), jnp.int16),
+        jax.ShapeDtypeStruct((B, Tc, C), jnp.int8),
+        jax.ShapeDtypeStruct((B, Tc), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+    )
     with _Capture() as cap:
-        mod.snn_chunk.__wrapped__(
-            weights, biases, betas, thresholds, u0, r0,
-            addrs, values, counts, active, interpret=True,
+        jax.eval_shape(
+            lambda *a: mod.snn_chunk.__wrapped__(
+                *a, interpret=True, layout="slot_major"),
+            *args,
         )
-    del L
     return _analyze_record("snn_chunk", cap.records[-1])
 
 
@@ -356,7 +461,9 @@ def _plan_q115_matmul() -> KernelPlan:
 
 
 KERNEL_PLANNERS: dict[str, Callable[[], KernelPlan]] = {
-    "snn_chunk": _plan_snn_chunk,
+    "snn_chunk": plan_snn_chunk,
+    "snn_chunk_128px": lambda: dataclasses.replace(
+        plan_snn_chunk(_COLLISION_128PX), kernel="snn_chunk_128px"),
     "aer_spike_matmul": _plan_aer_matmul,
     "aer_spike_matmul_batched": _plan_aer_matmul_batched,
     "lif_fused": _plan_lif_fused,
@@ -365,6 +472,7 @@ KERNEL_PLANNERS: dict[str, Callable[[], KernelPlan]] = {
 
 _KERNEL_PATHS = {
     "snn_chunk": "src/repro/kernels/snn_chunk.py",
+    "snn_chunk_128px": "src/repro/kernels/snn_chunk.py",
     "aer_spike_matmul": "src/repro/kernels/aer_matmul.py",
     "aer_spike_matmul_batched": "src/repro/kernels/aer_matmul.py",
     "lif_fused": "src/repro/kernels/lif_fused.py",
@@ -372,10 +480,49 @@ _KERNEL_PATHS = {
 }
 
 
+def plan_findings(
+    plan: KernelPlan,
+    vmem_budget: int = DEFAULT_VMEM_BUDGET,
+    smem_budget: int = DEFAULT_SMEM_BUDGET,
+    vmem_capacity: int = VMEM_CAPACITY,
+    path: str | None = None,
+) -> list[Finding]:
+    """What a plan breaks: a working set over the scoped limit the kernel
+    asks for (``vmem_budget`` where it asks none), a limit over the
+    core's VMEM, SMEM over its line, and the index-map errors."""
+    name = plan.kernel
+    path = path or _KERNEL_PATHS.get(name, f"<kernel:{name}>")
+    findings: list[Finding] = []
+    budget = plan.vmem_limit_bytes or vmem_budget
+    if plan.vmem_bytes > budget or budget > vmem_capacity:
+        findings.append(
+            Finding(
+                path, 1, 0, "RB301",
+                f"{name}: estimated VMEM working set "
+                f"{plan.vmem_bytes / 2**20:.2f} MiB, scoped limit "
+                f"{budget / 2**20:.2f} MiB, core VMEM "
+                f"{vmem_capacity / 2**20:.2f} MiB",
+            )
+        )
+    if plan.smem_bytes > smem_budget:
+        findings.append(
+            Finding(
+                path, 1, 0, "RB304",
+                f"{name}: SMEM {plan.smem_bytes / 2**10:.0f} KiB "
+                f"exceeds budget {smem_budget / 2**10:.0f} KiB",
+            )
+        )
+    for err in plan.errors:
+        code = "RB303" if "does not divide" in err else "RB302"
+        findings.append(Finding(path, 1, 0, code, f"{name}: {err}"))
+    return findings
+
+
 def check_kernel_budgets(
     vmem_budget: int = DEFAULT_VMEM_BUDGET,
     smem_budget: int = DEFAULT_SMEM_BUDGET,
     kernels: Sequence[str] | None = None,
+    vmem_capacity: int = VMEM_CAPACITY,
 ) -> tuple[list[KernelPlan], list[Finding]]:
     """Capture + analyse every kernel; returns (plans, findings)."""
     plans: list[KernelPlan] = []
@@ -390,24 +537,6 @@ def check_kernel_budgets(
             )
             continue
         plans.append(plan)
-        if plan.vmem_bytes > vmem_budget:
-            findings.append(
-                Finding(
-                    path, 1, 0, "RB301",
-                    f"{name}: estimated VMEM working set "
-                    f"{plan.vmem_bytes / 2**20:.2f} MiB exceeds budget "
-                    f"{vmem_budget / 2**20:.2f} MiB",
-                )
-            )
-        if plan.smem_bytes > smem_budget:
-            findings.append(
-                Finding(
-                    path, 1, 0, "RB304",
-                    f"{name}: SMEM {plan.smem_bytes / 2**10:.0f} KiB "
-                    f"exceeds budget {smem_budget / 2**10:.0f} KiB",
-                )
-            )
-        for err in plan.errors:
-            code = "RB303" if "does not divide" in err else "RB302"
-            findings.append(Finding(path, 1, 0, code, f"{name}: {err}"))
+        findings += plan_findings(plan, vmem_budget, smem_budget,
+                                  vmem_capacity, path)
     return plans, findings

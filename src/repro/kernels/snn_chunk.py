@@ -9,11 +9,15 @@ chunk runtime still stitched them together through HBM: per-step currents
 written out by the gather, read back by the LIF pass, and membrane state
 round-tripped between every step.  This kernel closes the loop:
 
-  - **per-step event lists ride in through SMEM**: each slot program gets
-    its own (Tc*C) address and value rows as SMEM blocks indexed by the
-    slot, and the (B*Tc) per-step counts are scalar-prefetched, so event
-    addresses can drive dynamic weight-row indexing while scalar memory
-    stays bounded by one slot's chunk whatever the slot count;
+  - **per-step event lists ride in through SMEM**: each grid step gets
+    one slot's address and value rows of ``ts`` steps as SMEM blocks, and
+    the (B*Tc) per-step counts are scalar-prefetched, so event addresses
+    can drive dynamic weight-row indexing while scalar memory stays
+    bounded by ``ts`` steps of one slot whatever the slot count.  ``ts``
+    is the whole chunk where its rows fit SMEM (the 64 px network's
+    C=4096) and fewer steps where they do not (C=16384 at 128 px): the
+    planner ``analysis.kernel_budget.snn_chunk_staging`` derives it, and
+    the kernel's scoped VMEM limit, from the shapes;
   - **membrane potential and refractory counters live in VMEM scratch for
     all Tc steps** — HBM traffic for state is exactly one read of the
     incoming (B, N) slot states and one write of the outgoing ones,
@@ -39,12 +43,14 @@ subtract reset, LIF and Lapicque dynamics, Q1.15 fake-quantized weights,
 and measured per-layer event counts all match to float32 tolerance
 (tests/test_snn_chunk.py).  On CPU the same kernel runs in interpret mode.
 
-Grid: (B,) — one program per batch slot; weights are broadcast blocks
-(index map constant in b) so each layer's slab is resident once, and slot
-programs are embarrassingly parallel.  Every per-slot array carries the
-slot as a leading axis that its block squeezes (``None``), so the block's
-last two dims are whole array dims, as Mosaic's (8, 128) tiling rule
-requires at any slot count.
+Grid: (B, Tc/ts) — one program per batch slot and block of ``ts`` steps;
+weights are broadcast blocks (index map constant over the grid) so each
+layer's slab is resident once, and slot programs are embarrassingly
+parallel.  A slot's membranes stay in scratch across its step blocks, and
+its output blocks stay resident until its last one.  Every per-slot
+array carries the slot as a leading axis that its block squeezes
+(``None``), so the block's last two dims are whole array dims, as
+Mosaic's (8, 128) tiling rule requires at any slot count.
 """
 
 from __future__ import annotations
@@ -56,6 +62,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.analysis import kernel_budget
 
 Array = jax.Array
 
@@ -73,11 +81,12 @@ def _round_up(x: int, m: int) -> int:
 def _chunk_kernel(
     act_ref,  # (B,) int32 prefetch: 1 = slot active, 0 = frozen
     cnt_ref,  # (B*Tc,) int32 prefetch: valid events per step
-    addr_ref,  # (1, Tc*C) int32 SMEM block: this slot's event addresses
-    val_ref,  # (1, Tc*C) f32 SMEM block: signed event values (0 = pad)
+    addr_ref,  # (1, ts*C) int32 SMEM block: this slot's event addresses
+    val_ref,  # (1, ts*C) f32 SMEM block: signed event values (0 = pad)
     *refs,
     num_layers: int,
     num_steps: int,
+    block_steps: int,
     cap: int,
     block_e: int,
     refractory_steps: int,
@@ -99,6 +108,7 @@ def _chunk_kernel(
     r_scr = refs[9 * L + 3 : 10 * L + 3]  # VMEM-resident refractory
 
     b = pl.program_id(0)
+    j = pl.program_id(1)  # block of block_steps steps
     is_active = act_ref[b] > 0
     ne = cap // block_e
 
@@ -113,35 +123,41 @@ def _chunk_kernel(
         spk_ref[...] = jnp.zeros_like(spk_ref)
         ev_ref[...] = jnp.zeros_like(ev_ref)
 
-    @pl.when(is_active)
-    def _run():
+    @pl.when(jnp.logical_and(is_active, j == 0))
+    def _load():
         for i in range(L):
             u_scr[i][...] = u0s[i][...]
             r_scr[i][...] = r0s[i][...]
 
+    @pl.when(is_active)
+    def _run():
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, _EV_PAD), 1)
 
-        def step(t, _):
+        def step(tb, _):
+            t = j * block_steps + tb  # step within the chunk
             # ---- layer 0: gated event-driven synaptic integration
             n0 = cnt_ref[b * num_steps + t]
-            base0 = t * cap
+            base0 = tb * cap
 
             def eblock(eb, acc):
                 base = base0 + eb * block_e
 
-                def gather(i, a):
-                    addr = addr_ref[0, base + i]
-                    v = val_ref[0, base + i]
-                    row = ws[0][pl.ds(addr, 1), :].astype(jnp.float32)
-                    return a + row * v
+                def gathers(a):
+                    # the block's gathers unrolled, in event order: a loop
+                    # of one gather a trip spends most of its time on loop
+                    # and scalar overhead (3.6x slower at 128 px, 10% at
+                    # 64 px on a v5e)
+                    for i in range(block_e):
+                        addr = addr_ref[0, base + i]
+                        v = val_ref[0, base + i]
+                        row = ws[0][pl.ds(addr, 1), :].astype(jnp.float32)
+                        a = a + row * v
+                    return a
 
                 # events are packed valid-first: a block past the count is
                 # pure padding — one scalar compare, no row gathers
                 return jax.lax.cond(
-                    eb * block_e < n0,
-                    lambda a: jax.lax.fori_loop(0, block_e, gather, a),
-                    lambda a: a,
-                    acc,
+                    eb * block_e < n0, gathers, lambda a: a, acc
                 )
 
             cur = jax.lax.fori_loop(
@@ -204,7 +220,8 @@ def _chunk_kernel(
             ev_ref[pl.ds(t, 1), :] = ev_row
             return 0
 
-        jax.lax.fori_loop(0, num_steps, step, 0)
+        jax.lax.fori_loop(0, block_steps, step, 0)
+        # written after every block; the slot's last one is kept
         for i in range(L):
             ufins[i][...] = u_scr[i][...]
             rfins[i][...] = r_scr[i][...]
@@ -302,48 +319,61 @@ def snn_chunk(
             jnp.pad(r0[i].astype(jnp.int32), ((0, 0), (0, pn)))[:, None]
         )
 
-    # flat per-slot event streams + per-step counts
+    # staging derived from the shapes: ts steps of one slot's event rows
+    # per grid step, and the scoped VMEM the working set needs
+    staging = kernel_budget.snn_chunk_staging(
+        [w.shape[0] for w in weights], outs, B, Tc, C, block_e=block_e
+    )
+    ts = staging.steps_per_block
+    nb = Tc // ts
+
+    # flat per-slot event streams, one row per block of ts steps, and
+    # per-step counts
     if layout == "slot_major":
-        addrs_f = addrs.reshape(B, 1, Tc * Cp).astype(jnp.int32)
-        values_f = values.reshape(B, 1, Tc * Cp).astype(jnp.float32)
+        addrs_f = addrs.reshape(B * nb, 1, ts * Cp).astype(jnp.int32)
+        values_f = values.reshape(B * nb, 1, ts * Cp).astype(jnp.float32)
         counts_f = counts.reshape(B * Tc).astype(jnp.int32)
     else:
         addrs_f = (
-            addrs.transpose(1, 0, 2).reshape(B, 1, Tc * Cp).astype(jnp.int32)
+            addrs.transpose(1, 0, 2)
+            .reshape(B * nb, 1, ts * Cp)
+            .astype(jnp.int32)
         )
         values_f = (
             values.transpose(1, 0, 2)
-            .reshape(B, 1, Tc * Cp)
+            .reshape(B * nb, 1, ts * Cp)
             .astype(jnp.float32)
         )
         counts_f = counts.transpose(1, 0).reshape(B * Tc).astype(jnp.int32)
     act = (jnp.asarray(active) != 0).astype(jnp.int32)
 
-    # one slot's (Tc*Cp) event rows per grid step: SMEM holds two such
+    # one slot's (ts*Cp) event rows per grid step: SMEM holds two such
     # blocks (double-buffered) per table, independent of B
     in_specs = [
         pl.BlockSpec(
-            (None, 1, Tc * Cp),
-            lambda b, *_: (b, 0, 0),
+            (None, 1, ts * Cp),
+            lambda b, j, *_: (b * nb + j, 0, 0),
             memory_space=pltpu.SMEM,
         )
         for _ in range(2)
     ]
     for i in range(L):
-        # index map constant in b: each slab is resident once, shared by
-        # every slot program
+        # index map constant over the grid: each slab is resident once,
+        # shared by every slot program
         in_specs.append(
-            pl.BlockSpec(ws[i].shape, lambda b, *_: (0, 0))
+            pl.BlockSpec(ws[i].shape, lambda b, j, *_: (0, 0))
         )
     for group in (bs, bet, thr):
         for i in range(L):
             in_specs.append(
-                pl.BlockSpec((1, np_out[i]), lambda b, *_: (0, 0))
+                pl.BlockSpec((1, np_out[i]), lambda b, j, *_: (0, 0))
             )
     def slot_block(*dims):
-        # leading slot axis squeezed: the kernel sees the trailing dims
+        # leading slot axis squeezed: the kernel sees the trailing dims;
+        # constant over a slot's step blocks, so outputs stay resident
+        # until its last one
         return pl.BlockSpec(
-            (None, *dims), lambda b, *_: (b,) + (0,) * len(dims)
+            (None, *dims), lambda b, j, *_: (b,) + (0,) * len(dims)
         )
 
     for group in (u0p, r0p):
@@ -373,7 +403,7 @@ def snn_chunk(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B,),
+        grid=(B, nb),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
@@ -383,6 +413,7 @@ def snn_chunk(
             _chunk_kernel,
             num_layers=L,
             num_steps=Tc,
+            block_steps=ts,
             cap=Cp,
             block_e=be,
             refractory_steps=refractory_steps,
@@ -393,7 +424,8 @@ def snn_chunk(
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=staging.vmem_limit_bytes,
         ),
         interpret=interpret,
     )(act, counts_f, addrs_f, values_f, *ws, *bs, *bet, *thr, *u0p, *r0p)

@@ -152,6 +152,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.analysis import kernel_budget
 from repro.checkpoint.manager import (
     CheckpointCorruptError,
     gc_orphan_tmpdirs,
@@ -445,6 +446,7 @@ class SNNStreamEngine:
         fault_checks = self.fault_checks
         capacities = self.capacities
         mesh, num_slots = self.mesh, self.S
+        self._plan_kernel(backend)
 
         def _chunk_fn(prepared, states, ring, meta):
             # scheduling metadata lives on device: per-slot consumed-step
@@ -581,6 +583,21 @@ class SNNStreamEngine:
             self._slot_jit(body, ranks, donate_argnums=(1, 3)),
             self._slot_jit(body, ranks),
         )
+
+    def _plan_kernel(self, backend: str) -> None:
+        """Set the gauges of what one fused kernel call stages, as the
+        planner prices it from the shapes (slots per device on a mesh);
+        zero on a backend that runs no fused kernel."""
+        plan = None
+        if backend == "fused":
+            sizes = self.cfg.layer_sizes
+            slots = (self.S if self.mesh is None else
+                     self._slot_sharding(1).shard_shape((self.S,))[0])
+            plan = kernel_budget.snn_chunk_staging(
+                sizes[:-1], sizes[1:], slots, self.Tc, self.C)
+        self._m_k_weight.set(plan.weight_bytes if plan else 0)
+        self._m_k_events.set(plan.event_row_bytes if plan else 0)
+        self._m_k_vmem.set(plan.vmem_limit_bytes if plan else 0)
 
     def _slot_sharding(self, ndim: int) -> NamedSharding:
         """The one sharding of a rank-``ndim`` slot-leading array on the
@@ -784,6 +801,12 @@ class SNNStreamEngine:
         )
         self._m_qdepth = m.gauge("engine.queue.depth")
         self._m_active = m.gauge("engine.slots.active")
+        # the fused kernel's plan per call (_plan_kernel): layer-0 weight
+        # bytes brought into VMEM, event-row bytes staged into SMEM, and
+        # the scoped VMEM limit it is given
+        self._m_k_weight = m.gauge("engine.kernel.layer0_weight_bytes")
+        self._m_k_events = m.gauge("engine.kernel.event_row_bytes")
+        self._m_k_vmem = m.gauge("engine.kernel.vmem_limit_bytes")
         # fault-tolerance instruments: admission-plane dispositions
         # (lifetime), chunk-supervisor events, injector applications,
         # and the episode-scoped exclusion counters events_per_sec()
@@ -2325,7 +2348,9 @@ class SNNStreamEngine:
         (CPU here) it *includes* the device compute wait — read it as
         "tick minus host work", not as host dispatch overhead to
         attack.  ``stats_fetch_us`` is the blocking stats retirement
-        (any remaining device wait + the single D2H fetch)."""
+        (any remaining device wait + the single D2H fetch).  The
+        ``kernel_*`` keys are the fused kernel's plan per call (zero on
+        another backend)."""
         n = max(self._m_prep.count, 1)
         return {
             "ticks": self._m_prep.count,
@@ -2334,6 +2359,9 @@ class SNNStreamEngine:
             "dispatch_us": self._m_dispatch.sum / n * 1e6,
             "stats_fetch_us": self._m_fetch.sum / n * 1e6,
             "dispatch_p99_us": self._m_dispatch.percentile(99) * 1e6,
+            "kernel_layer0_weight_bytes": self._m_k_weight.value,
+            "kernel_event_row_bytes": self._m_k_events.value,
+            "kernel_vmem_limit_bytes": self._m_k_vmem.value,
         }
 
     # -------------------------------------------------------- benchmarks

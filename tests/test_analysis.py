@@ -25,7 +25,13 @@ from repro.analysis import (
     runtime_donation_check,
     verify_donation,
 )
-from repro.analysis.kernel_budget import KERNEL_PLANNERS
+from repro.analysis.kernel_budget import (
+    KERNEL_PLANNERS,
+    VMEM_CAPACITY,
+    plan_findings,
+    plan_snn_chunk,
+    snn_chunk_staging,
+)
 from repro.events import aer, runtime
 
 
@@ -272,13 +278,18 @@ def test_kernel_budgets_all_kernels_fit():
     assert {p.kernel for p in plans} == set(KERNEL_PLANNERS)
     for p in plans:
         assert p.errors == []
-        assert 0 < p.vmem_bytes <= DEFAULT_VMEM_BUDGET
+        # a kernel's budget is the scoped limit it asks for, else the
+        # compiler's default, and never more than the core holds
+        budget = p.vmem_limit_bytes or DEFAULT_VMEM_BUDGET
+        assert 0 < p.vmem_bytes <= budget <= VMEM_CAPACITY
         assert p.smem_bytes <= DEFAULT_SMEM_BUDGET
         assert p.grid, p.kernel
 
 
 def test_kernel_budget_overflow_flagged():
-    plans, findings = check_kernel_budgets(vmem_budget=1024)
+    # a core of 1 KiB: no kernel's working set or asked limit fits
+    plans, findings = check_kernel_budgets(vmem_budget=1024,
+                                           vmem_capacity=1024)
     assert findings and all(f.code == "RB301" for f in findings)
     assert len(findings) == len(plans)
 
@@ -290,6 +301,35 @@ def test_snn_chunk_plan_shape():
     assert "scratch" in roles
     assert plan.num_scalar_prefetch >= 1
     assert plan.smem_bytes > 0
+
+
+@pytest.mark.parametrize("sizes,steps_per_block", [
+    ((4096, 512, 2), 5),  # 64 px: a slot's whole chunk of rows in SMEM
+    ((16384, 512, 2), 1),  # 128 px: a step at a time, a 32 MiB slab
+])
+def test_snn_chunk_priced_at_the_served_geometry(sizes, steps_per_block):
+    """8 slots, Tc=5, C = fan-in: the planner's price is what the kernel
+    hands Mosaic, nothing is over, and the limit covers the set."""
+    plan = plan_snn_chunk(sizes, slots=8, chunk_steps=5)
+    assert plan_findings(plan) == []
+    staging = snn_chunk_staging(sizes[:-1], sizes[1:], 8, 5, sizes[0])
+    assert staging.steps_per_block == steps_per_block
+    assert plan.grid == (8, 5 // steps_per_block)
+    assert plan.smem_bytes == staging.smem_bytes <= DEFAULT_SMEM_BUDGET
+    assert plan.vmem_bytes == staging.vmem_bytes
+    assert plan.vmem_limit_bytes == staging.vmem_limit_bytes
+    assert staging.vmem_bytes < staging.vmem_limit_bytes <= VMEM_CAPACITY
+    assert staging.weight_bytes == sizes[0] * 512 * 4
+    assert staging.event_row_bytes == 8 * 5 * sizes[0] * 8
+
+
+def test_snn_chunk_slab_past_the_core_is_a_finding():
+    """A 65536-wide layer 0 (a 128 MiB slab) is priced, not crashed on:
+    the limit it would need passes the core's VMEM."""
+    plan = plan_snn_chunk((65536, 512, 2), slots=8, chunk_steps=5,
+                          capacity=4096)
+    assert plan.vmem_limit_bytes > VMEM_CAPACITY
+    assert [f.code for f in plan_findings(plan)] == ["RB301"]
 
 
 # ------------------------------------------------------------------ recompile detector

@@ -399,6 +399,30 @@ def test_engine_metrics_snapshot_consistency():
     assert snap["engine.request.energy_pj"]["sum"] > 0
 
 
+@pytest.mark.parametrize("backend", ["fused", "jnp"])
+def test_engine_reports_the_fused_kernels_plan(backend):
+    """The snapshot and the tick breakdown carry what one fused kernel
+    call stages, as the planner prices it; zero where no fused kernel
+    runs."""
+    from repro.analysis import kernel_budget
+
+    eng = SNNStreamEngine(_params(), CFG, num_slots=2, chunk_steps=5,
+                          backend=backend)
+    snap, tb = eng.metrics_snapshot(), eng.tick_breakdown()
+    plan = kernel_budget.snn_chunk_staging((64, 24), (24, 2), 2, 5, 64)
+    want = {
+        "layer0_weight_bytes": 64 * 128 * 4,  # widths pad to 128 lanes
+        "event_row_bytes": 2 * 5 * 64 * 8,  # int32 address, f32 value
+        "vmem_limit_bytes": plan.vmem_limit_bytes,
+    }
+    for key, value in want.items():
+        value = value if backend == "fused" else 0
+        assert snap[f"engine.kernel.{key}"] == {"type": "gauge",
+                                                "value": value}
+        assert tb[f"kernel_{key}"] == value
+    assert plan.vmem_limit_bytes >= plan.vmem_bytes
+
+
 def test_wall_s_resets_per_episode():
     """Regression: wall_s was initialized in __init__ but never reset in
     _begin_episode, so a mid-episode events_per_sec() read could see the

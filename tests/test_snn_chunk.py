@@ -143,6 +143,57 @@ def test_fused_parity_frozen_slots():
     )
 
 
+@pytest.mark.parametrize("smem_budget,steps_per_block", [
+    (None, 5),  # the rows of the whole chunk fit SMEM: one block a slot
+    (8192, 1),  # they do not: the planner stages them a step at a time
+])
+def test_fused_staging_matches_oracle_and_reference(
+    monkeypatch, smem_budget, steps_per_block
+):
+    """Both stagings the planner derives from the shapes give
+    ``run_chunk``'s jnp oracle and the benchmark's plain reference
+    (``perfbench/references/lif_mlp.py``) on its seeded random weights.
+    The per-step side is forced by shrinking the planner's SMEM line, as
+    a 16384-wide layer 0 forces it on the chip."""
+    from perfbench.references import lif_mlp
+    from repro.analysis import kernel_budget
+
+    if smem_budget is not None:
+        monkeypatch.setattr(kernel_budget, "DEFAULT_SMEM_BUDGET", smem_budget)
+    sizes, B, Tc = (256, 32, 2), 3, 5
+    plan = kernel_budget.snn_chunk_staging(sizes[:-1], sizes[1:], B, Tc, 256)
+    assert plan.steps_per_block == steps_per_block
+    jax.clear_caches()  # the staging is fixed when the kernel is traced
+
+    cfg = snn.SNNConfig(layer_sizes=sizes, num_steps=Tc)
+    ref_cfg = {"layer_sizes": list(sizes), "beta": 0.9, "threshold": 1.0,
+               "out_gain": 8.0}
+    params = lif_mlp.make_params(ref_cfg, jax.random.PRNGKey(16))
+    spikes = _spikes(Tc, B, sizes[0], 0.4)
+    states = runtime.init_states(cfg, B)
+    outs = {
+        backend: runtime.run_chunk(params, states, spikes, cfg,
+                                   backend=backend)
+        for backend in ("jnp", "fused")
+    }
+    (sf, mf, pf, ef), (sj, mj, pj, ej) = outs["fused"], outs["jnp"]
+    np.testing.assert_allclose(np.asarray(mf), np.asarray(mj),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(pf), np.asarray(pj))
+    np.testing.assert_array_equal(np.asarray(ef), np.asarray(ej))
+    for a, b in zip(sf, sj):
+        np.testing.assert_allclose(np.asarray(a.u), np.asarray(b.u),
+                                   atol=1e-5, rtol=1e-5)
+
+    hid, counts, memsum, _ = lif_mlp.forward(params, spikes)
+    np.testing.assert_array_equal(np.asarray(ef)[:, 1].sum(0),
+                                  np.asarray(hid))
+    np.testing.assert_array_equal(np.asarray(pf).sum(0), np.asarray(counts))
+    np.testing.assert_allclose(np.asarray(mf).sum(0), np.asarray(memsum),
+                               atol=1e-4, rtol=1e-5)
+    assert np.asarray(counts).sum() > 0  # the output layer fired
+
+
 def test_fused_rejects_truncating_hidden_capacity():
     """The fused kernel cannot truncate hidden layers (dense in-VMEM
     matvecs); a plan that would make fused and jnp diverge must be

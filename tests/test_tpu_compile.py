@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -60,9 +62,20 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("slots,cap", [(4, 1152), (8, 4096), (32, 1152)])
-def test_snn_chunk_compiles_for_v5e(one_chip, no_compile_cache, slots, cap):
-    sizes = CONFIG.layer_sizes  # 4096-512-2
+CONFIG_128PX = dataclasses.replace(CONFIG, layer_sizes=(16384, 512, 2))
+
+
+@pytest.mark.parametrize("sizes,slots,cap", [
+    (CONFIG.layer_sizes, 4, 1152),
+    (CONFIG.layer_sizes, 8, 4096),
+    (CONFIG.layer_sizes, 32, 1152),
+    # 128 px: event rows a step at a time, a 32 MiB slab under the
+    # planner's scoped VMEM limit
+    (CONFIG_128PX.layer_sizes, 8, 16384),
+], ids=["4-1152", "8-4096", "32-1152", "128px-8-16384"])
+def test_snn_chunk_compiles_for_v5e(
+    one_chip, no_compile_cache, sizes, slots, cap
+):
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -93,12 +106,24 @@ def test_engine_chunk_compiles_for_v5e(
 ):
     """The engine's whole jitted tick chunk at its defaults (8 slots,
     Tc=5, C=4096), as ``backend="auto"`` resolves it on a TPU."""
+    _engine_chunk_compiles(CONFIG, one_chip, monkeypatch)
+
+
+def test_engine_chunk_compiles_for_v5e_at_128px(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """The same at the paper's 128 px input, 16384-512-2 (C=16384)."""
+    _engine_chunk_compiles(CONFIG_128PX, one_chip, monkeypatch)
+
+
+def _engine_chunk_compiles(cfg, one_chip, monkeypatch):
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     engine = SNNStreamEngine(
-        snn.init_params(jax.random.PRNGKey(0), CONFIG), CONFIG
+        snn.init_params(jax.random.PRNGKey(0), cfg), cfg
     )
+    K = cfg.layer_sizes[0]
     assert (engine.backend, engine.S, engine.Tc, engine.C) == (
-        "fused", 8, TC, 4096,
+        "fused", 8, TC, K,
     )
     args = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
